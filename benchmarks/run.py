@@ -50,6 +50,9 @@ def main() -> None:
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="write the BENCH_serving.json payload")
     args, passthrough = ap.parse_known_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.json and args.filter:
         # --json here means the SERVING payload; a module's own JSON flag
         # would be silently shadowed — force the unambiguous invocation.

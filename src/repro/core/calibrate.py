@@ -88,15 +88,14 @@ def calibration_cache_dir(override: str | None = None) -> str:
     return os.path.join(os.path.expanduser("~"), ".cache", "vortex")
 
 
-def hardware_fingerprint(
-    hw, backends: tuple[str, ...], impl: str, interpret: bool
-) -> dict:
+def hardware_fingerprint(hw, backends: tuple[str, ...], impl: str) -> dict:
     """A JSON-able descriptor of everything a measured time depends on:
     the HardwareSpec (name + per-backend peaks + native tiles), the
-    executable lowering (impl/interpret), and the host identity the
-    measurements actually ran on (jax version, device platform/kind,
-    machine).  Two processes with equal fingerprints may share calibrated
-    tables; anything else must re-measure."""
+    executable lowering (impl; the device platform decides whether Pallas
+    is interpreted), and the host identity the measurements actually ran
+    on (jax version, device platform/kind, machine).  Two processes with
+    equal fingerprints may share calibrated tables; anything else must
+    re-measure."""
     import jax
 
     dev = jax.devices()[0]
@@ -105,7 +104,6 @@ def hardware_fingerprint(
         "backends": {b: float(hw.backends[b]) for b in backends},
         "native_tile": {b: list(hw.native_tile[b]) for b in backends},
         "impl": impl,
-        "interpret": bool(interpret),
         "jax": jax.__version__,
         "device": f"{dev.platform}:{getattr(dev, 'device_kind', '')}",
         "machine": platform.machine(),
@@ -312,7 +310,8 @@ class Calibrator:
         for idx in idxs:
             cand = sel.candidate_selection(idx, m)
             fn = wl.build_executable(
-                cand, impl=kernel.impl, interpret=kernel.interpret
+                cand, impl=kernel.impl,
+                vmem_limit_bytes=kernel.vmem_limit_bytes,
             )
             warm = wl.example_args(cand)
             aot = jax.jit(fn).lower(*warm).compile()
@@ -457,9 +456,7 @@ class Calibrator:
         for kernel in list(self._kernels()):
             hw = kernel.selector._hw
             backends = tuple(sorted(kernel.selector.scored))
-            return hardware_fingerprint(
-                hw, backends, kernel.impl, kernel.interpret
-            )
+            return hardware_fingerprint(hw, backends, kernel.impl)
         raise RuntimeError("no kernels to fingerprint")
 
     def cache_path(self) -> str:

@@ -42,14 +42,12 @@ class DenylistStore:
         hw,
         backends: tuple[str, ...],
         impl: str,
-        interpret: bool,
         *,
         cache_dir: str | None = None,
     ):
         self._hw = hw
         self._backends = tuple(backends)
         self._impl = impl
-        self._interpret = bool(interpret)
         self._cache_dir = cache_dir
         self._lock = threading.Lock()
         self._loaded = False
@@ -73,9 +71,7 @@ class DenylistStore:
                 hardware_fingerprint,
             )
 
-            fp = hardware_fingerprint(
-                self._hw, self._backends, self._impl, self._interpret
-            )
+            fp = hardware_fingerprint(self._hw, self._backends, self._impl)
             self._path = os.path.join(
                 calibration_cache_dir(self._cache_dir),
                 f"{fingerprint_key(fp)}.deny.json",

@@ -27,8 +27,10 @@ Execution backends:
   * ``xla``    — flat JAX ops on the bucket shape (host-CPU execution in
                  this container; what the benchmarks time),
   * ``pallas`` — the Vortex-tiled Pallas TPU kernels (kernels/) with
-                 BlockSpecs taken from the selected strategy; run in
-                 interpret mode off-TPU and compile natively on TPU.
+                 BlockSpecs taken from the selected strategy and the
+                 hardware's level-1 capacity as their VMEM limit; they
+                 compile natively on a TPU and run in interpret mode only
+                 on the CPU (``kernels.gemm.interpret_pallas``).
 """
 from __future__ import annotations
 
@@ -420,7 +422,6 @@ class VortexKernel:
         backends: tuple[str, ...] | None = None,
         num_cores: int = 1,
         impl: str = "xla",
-        interpret: bool = True,
         scored_cache: dict | None = None,
         table_m_max: int = 4096,
         table_extend_limit: int = 1 << 17,
@@ -432,7 +433,6 @@ class VortexKernel:
         self._hw = hw
         self._wl = wl
         self._impl = impl
-        self._interpret = interpret
         self._staging = staging and wl.supports_staging
         self._pool_cap = staging_pool_cap
         self._max_retries = max(int(max_retries), 0)
@@ -499,8 +499,10 @@ class VortexKernel:
         return self._impl
 
     @property
-    def interpret(self) -> bool:
-        return self._interpret
+    def vmem_limit_bytes(self) -> int:
+        """The VMEM a Pallas executable may claim: the level-1 capacity
+        the lattice sized every candidate tile against."""
+        return self._hw.level(1).capacity_bytes
 
     # -- executable construction ------------------------------------------
 
@@ -508,7 +510,7 @@ class VortexKernel:
         if faults.ACTIVE is not None:
             faults.ACTIVE.check("precompile")
         fn = self._wl.build_executable(
-            sel, impl=self._impl, interpret=self._interpret
+            sel, impl=self._impl, vmem_limit_bytes=self.vmem_limit_bytes
         )
         jfn = jax.jit(fn)
         t0 = time.perf_counter()
@@ -824,9 +826,7 @@ class VortexKernel:
         )
         entry = self._exec_cache.get(key)
         if entry is None:
-            fn = wl.build_executable(
-                sel, impl="xla", interpret=self._interpret
-            )
+            fn = wl.build_executable(sel, impl="xla")
             entry = _CacheEntry(fn=jax.jit(fn), compile_seconds=0.0)
             self._exec_cache[key] = entry
         entry.hits += 1

@@ -19,7 +19,7 @@ analyzer in this (CPU-only) container and mirrors the paper's CPU target.
 from __future__ import annotations
 
 import dataclasses
-from typing import Mapping, Sequence
+from typing import Mapping, NamedTuple, Sequence
 
 __all__ = [
     "HardwareLevel",
@@ -27,6 +27,8 @@ __all__ = [
     "TPU_V5E",
     "HOST_CPU",
     "get_hardware",
+    "Platform",
+    "resolve_platform",
 ]
 
 
@@ -121,9 +123,11 @@ def _tpu_v5e() -> HardwareSpec:
             depth=1,
             name="vmem",
             parallel_units=1,
-            # 128 MiB VMEM per v5e core; leave headroom for the compiler's
-            # own scratch: strategies may claim at most half.
-            capacity_bytes=64 * 1024 * 1024,
+            # 128 MiB VMEM per v5e core.  A tile's counted footprint
+            # (Workload.l1_tile_bytes) must fit this, and the kernels pass
+            # it to the compiler as vmem_limit_bytes: the compiler's own
+            # default scoped limit (16 MiB) refuses the larger selections.
+            capacity_bytes=96 * 1024 * 1024,
             load_bandwidth=hbm_bw,
             compute_flops=0.0,
         ),
@@ -208,3 +212,37 @@ def get_hardware(name: str) -> HardwareSpec:
         raise KeyError(
             f"unknown hardware {name!r}; known: {sorted(_REGISTRY)}"
         ) from None
+
+
+# TPU ``device_kind`` (as JAX reports it) -> spec name.  A kind missing here
+# has no limits in this file, and v5e's would mis-size every tile.
+_TPU_KINDS: dict[str, str] = {"TPU v5 lite": "tpu_v5e"}
+
+
+class Platform(NamedTuple):
+    """What an attached device runs the engine as."""
+
+    hardware: HardwareSpec
+    native_pallas: bool  # Pallas compiles for it (else: interpret mode)
+
+
+def resolve_platform(device=None) -> Platform:
+    """The platform of ``device`` (default: the first attached one).
+
+    A TPU maps by its ``device_kind`` to its spec, and compiles Pallas
+    natively; a kind with no spec raises.  The CPU is ``host_cpu``, and
+    runs Pallas kernels in interpret mode.  Any other platform raises.
+    """
+    if device is None:
+        import jax
+
+        device = jax.devices()[0]
+    if device.platform == "cpu":
+        return Platform(HOST_CPU, native_pallas=False)
+    name = _TPU_KINDS.get(device.device_kind)
+    if device.platform != "tpu" or name is None:
+        raise ValueError(
+            f"no hardware spec for {device.platform} device kind "
+            f"{device.device_kind!r}; known TPU kinds: {sorted(_TPU_KINDS)}"
+        )
+    return Platform(get_hardware(name), native_pallas=True)

@@ -210,12 +210,14 @@ class Workload:
         return (m * k + k * n) * self.dtype_bytes + m * n * self.acc_bytes
 
     def l1_tile_bytes(self, tile: Tile) -> int:
-        """VMEM working set of one layer-1 tile (double-buffered streams +
-        resident f32 accumulator)."""
+        """VMEM bytes the Pallas kernel allocates for one layer-1 tile: the
+        pipeline double-buffers every block (two A, two B and two output
+        blocks), plus the resident f32 accumulator scratch.  Candidate
+        generation holds this under the level-1 capacity, which is also
+        the ``vmem_limit_bytes`` the kernel hands the compiler."""
         m, n, k = tile
-        stream = 2 * (m * k + k * n) * self.dtype_bytes
-        acc = m * n * self.acc_bytes
-        return stream + acc
+        blocks = (m * k + k * n + m * n) * self.dtype_bytes
+        return 2 * blocks + m * n * self.acc_bytes
 
     def l0_axis_multipliers(self) -> Tile:
         """Upper pow2 multipliers over the native tile for level-0 ranges."""
@@ -343,12 +345,14 @@ class Workload:
         raise NotImplementedError
 
     def build_executable(
-        self, sel, *, impl: str, interpret: bool
+        self, sel, *, impl: str, vmem_limit_bytes: int | None = None
     ) -> Callable:
         """Build the fused bucket-shaped executable for a runtime selection:
         ``fn(*bucket_view_args, *runtime_scalars) -> bucket-shaped out``.
-        Raises :class:`SelectionDeviationError` rather than adjusting the
-        selected tile."""
+        ``vmem_limit_bytes`` is what a Pallas kernel may claim from the
+        compiler (the hardware's level-1 capacity).  Raises
+        :class:`SelectionDeviationError` rather than adjusting the selected
+        tile."""
         raise NotImplementedError
 
     def example_args(self, sel, *args) -> tuple:
@@ -440,7 +444,9 @@ class GemmWorkload(Workload):
         m = a.shape[0]
         return out[:m] if sel.padded_m != m else out
 
-    def build_executable(self, sel, *, impl: str, interpret: bool):
+    def build_executable(
+        self, sel, *, impl: str, vmem_limit_bytes: int | None = None
+    ):
         import jax
         import jax.numpy as jnp
 
@@ -455,7 +461,7 @@ class GemmWorkload(Workload):
             def fn(a, b, m_true):
                 return vortex_gemm(
                     a, b, m_true, block_m=m1, block_n=n1, block_k=k1,
-                    interpret=interpret,
+                    vmem_limit_bytes=vmem_limit_bytes,
                 )
 
         else:
@@ -626,7 +632,9 @@ class GroupedGemmWorkload(Workload):
         c = x.shape[1]
         return out[:, :c] if sel.padded_m != c else out
 
-    def build_executable(self, sel, *, impl: str, interpret: bool):
+    def build_executable(
+        self, sel, *, impl: str, vmem_limit_bytes: int | None = None
+    ):
         import jax.numpy as jnp
 
         m1, n1, k1 = sel.strategy.l1
@@ -639,7 +647,7 @@ class GroupedGemmWorkload(Workload):
             def fn(x, w, counts):
                 return vortex_grouped_gemm(
                     x, w, counts, block_m=m1, block_n=n1, block_k=k1,
-                    interpret=interpret,
+                    vmem_limit_bytes=vmem_limit_bytes,
                 )
 
         else:
@@ -762,9 +770,11 @@ class AttentionWorkload(Workload):
     def l1_tile_bytes(self, tile: Tile) -> int:
         m1, _, k1 = tile
         d = self.head_dim
-        stream = 2 * (m1 * d + 2 * k1 * d) * self.dtype_bytes  # Q + K,V
-        resident = m1 * d * self.acc_bytes + m1 * k1 * 4  # acc + f32 scores
-        return stream + resident
+        # Two buffers each of the q, k, v and output blocks.
+        blocks = 2 * (2 * m1 * d + 2 * k1 * d) * self.dtype_bytes
+        # acc + running max/sum scratch, and the f32 score block.
+        resident = m1 * d * self.acc_bytes + 2 * m1 * 4 + m1 * k1 * 4
+        return blocks + resident
 
     def l0_axis_multipliers(self) -> Tile:
         return (16, 1, 4)  # n pinned to the native lane tile
@@ -834,7 +844,9 @@ class AttentionWorkload(Workload):
         sq = q.shape[-2]
         return out[..., :sq, :] if sel.bucket[0] != sq else out
 
-    def build_executable(self, sel, *, impl: str, interpret: bool):
+    def build_executable(
+        self, sel, *, impl: str, vmem_limit_bytes: int | None = None
+    ):
         pq, _, pkv = sel.bucket
         m1, _, k1 = sel.strategy.l1
         _check_bucket_tiles(
@@ -849,7 +861,7 @@ class AttentionWorkload(Workload):
                 return flash_attention(
                     q, k, v, kv_len, block_q=m1, block_k=k1,
                     causal=causal, window=window, softcap=softcap,
-                    interpret=interpret,
+                    vmem_limit_bytes=vmem_limit_bytes,
                 )
 
         else:
@@ -1037,7 +1049,9 @@ class DecodeAttentionWorkload(AttentionWorkload):
     def finalize(self, sel, out, q, k, v, kv_len):
         return out  # (b, hq, 1, d) — never bucket-shaped
 
-    def build_executable(self, sel, *, impl: str, interpret: bool):
+    def build_executable(
+        self, sel, *, impl: str, vmem_limit_bytes: int | None = None
+    ):
         pkv = sel.bucket[2]
         _, _, k1 = sel.strategy.l1
         _check_bucket_tiles(self.kind, sel, (("kv", pkv, k1),))
@@ -1052,7 +1066,8 @@ class DecodeAttentionWorkload(AttentionWorkload):
                 return flash_attention(
                     q, k, v, kv_len, q_offset=kv_len - 1,
                     block_q=1, block_k=k1, causal=False,
-                    window=window, softcap=softcap, interpret=interpret,
+                    window=window, softcap=softcap,
+                    vmem_limit_bytes=vmem_limit_bytes,
                 )
 
         else:
@@ -1205,14 +1220,16 @@ class Conv2dWorkload(Workload):
         m = x.shape[0] * ho * wo
         return out[:m, : self.cout].reshape(x.shape[0], ho, wo, self.cout)
 
-    def build_executable(self, sel, *, impl: str, interpret: bool):
+    def build_executable(
+        self, sel, *, impl: str, vmem_limit_bytes: int | None = None
+    ):
         # The executable is the GEMM-view kernel on the im2col matrix; the
         # im2col expansion itself runs eagerly in stage_view() so the cached
         # artifact depends only on the bucket, not on (b, h, w) directly.
         return GemmWorkload(
             M=None, N=self.N, K=self.K, dtype_bytes=self.dtype_bytes,
             acc_bytes=self.acc_bytes,
-        ).build_executable(sel, impl=impl, interpret=interpret)
+        ).build_executable(sel, impl=impl, vmem_limit_bytes=vmem_limit_bytes)
 
     def example_args(self, sel, *args) -> tuple:
         import jax.numpy as jnp

@@ -28,8 +28,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-from repro.kernels.gemm import validate_blocks
+from repro.kernels.gemm import interpret_pallas, validate_blocks
 
 __all__ = ["flash_attention"]
 
@@ -120,6 +119,7 @@ def _attn_kernel(
     jax.jit,
     static_argnames=(
         "block_q", "block_k", "causal", "window", "softcap", "interpret",
+        "vmem_limit_bytes",
     ),
 )
 def flash_attention(
@@ -134,7 +134,8 @@ def flash_attention(
     causal: bool = True,
     window: int | None = None,
     softcap: float | None = None,
-    interpret: bool = False,
+    interpret: bool | None = None,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """Multi-head attention.
 
@@ -216,9 +217,10 @@ def flash_attention(
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
-        interpret=interpret,
+        interpret=interpret_pallas(interpret),
     )(kv_arr, qf, kf, vf)
     return out.reshape(b, hq, sq, d)

@@ -41,7 +41,7 @@ def vortex_conv2d(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 128,
-    interpret: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Conv2D (VALID) through im2col + masked-tail Vortex GEMM.
 

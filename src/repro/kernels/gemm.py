@@ -30,9 +30,28 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
+from repro.core.hardware import resolve_platform
 
-__all__ = ["vortex_gemm", "validate_blocks"]
+__all__ = ["vortex_gemm", "validate_blocks", "interpret_pallas"]
+
+
+def interpret_pallas(requested: bool | None = None) -> bool:
+    """Whether a Pallas kernel runs in interpret mode, from the platform.
+
+    ``None`` derives it: interpreted on the CPU, compiled natively on a
+    TPU.  ``False`` compiles natively anywhere (a described, unattached TPU
+    compiles from a CPU process).  ``True`` on a TPU raises: the chip
+    would run the interpreter, and nothing would say so.
+    """
+    native = resolve_platform().native_pallas
+    if requested is None:
+        return not native
+    if requested and native:
+        raise ValueError(
+            "Pallas interpret mode was requested on a TPU backend; "
+            "kernels compile natively there (pass interpret=None)"
+        )
+    return requested
 
 
 def validate_blocks(kind: str, **blocks: int) -> None:
@@ -105,7 +124,10 @@ def _gemm_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_m", "block_n", "block_k", "interpret", "out_dtype"),
+    static_argnames=(
+        "block_m", "block_n", "block_k", "interpret", "out_dtype",
+        "vmem_limit_bytes",
+    ),
 )
 def vortex_gemm(
     a: jax.Array,
@@ -115,8 +137,9 @@ def vortex_gemm(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 128,
-    interpret: bool = False,
+    interpret: bool | None = None,
     out_dtype=None,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """C[M,N] = A[M,K] @ B[K,N] with Vortex layer-1 tiles as BlockSpecs.
 
@@ -161,8 +184,9 @@ def vortex_gemm(
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
-        interpret=interpret,
+        interpret=interpret_pallas(interpret),
     )(m_arr, a, b)

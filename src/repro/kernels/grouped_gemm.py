@@ -29,8 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
-from repro.kernels.gemm import validate_blocks
+from repro.kernels.gemm import interpret_pallas, validate_blocks
 
 __all__ = ["vortex_grouped_gemm"]
 
@@ -90,7 +89,10 @@ def _grouped_gemm_kernel(
 
 @functools.partial(
     jax.jit,
-    static_argnames=("block_m", "block_n", "block_k", "interpret", "out_dtype"),
+    static_argnames=(
+        "block_m", "block_n", "block_k", "interpret", "out_dtype",
+        "vmem_limit_bytes",
+    ),
 )
 def vortex_grouped_gemm(
     x: jax.Array,
@@ -100,8 +102,9 @@ def vortex_grouped_gemm(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 128,
-    interpret: bool = False,
+    interpret: bool | None = None,
     out_dtype=None,
+    vmem_limit_bytes: int | None = None,
 ) -> jax.Array:
     """out[g] = x[g] @ w[g // r] with per-group masked-tail row extents.
 
@@ -148,8 +151,9 @@ def vortex_grouped_gemm(
         out_specs=pl.BlockSpec((1, block_m, block_n), lambda i, j, k: (i // gm, i % gm, j)),
         out_shape=jax.ShapeDtypeStruct((G, C, N), out_dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         ),
-        interpret=interpret,
+        interpret=interpret_pallas(interpret),
     )(counts_arr, x, w)

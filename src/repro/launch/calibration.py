@@ -110,6 +110,7 @@ def main(argv: list[str] | None = None) -> int:
     import jax.numpy as jnp
     import numpy as np
 
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.vortex import Engine
 
     ap = argparse.ArgumentParser(description=__doc__)
@@ -124,6 +125,7 @@ def main(argv: list[str] | None = None) -> int:
                     help="reduced bucket set / round counts")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     eng = Engine(
         "host_cpu", empirical_levels=(),
         calibration="on-idle",

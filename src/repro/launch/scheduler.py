@@ -311,7 +311,7 @@ class ContinuousScheduler:
         srv = self.server
         b, s = req.tokens.shape
         bp = srv.batch_bucket(b)
-        sp = srv.seq_bucket(s)
+        sp = srv.prefill_seq_bucket(s)
         batch = srv._make_batch(bp, sp, req.tokens)
         logits, rcache = srv._prefill_exec_for(bp, sp, batch)(
             srv.params, batch

@@ -57,6 +57,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import math
 import threading
 import time
@@ -68,6 +69,8 @@ import numpy as np
 
 from repro.core import AttentionWorkload, DecodeAttentionWorkload, GemmWorkload
 from repro.core.engine import DispatchStats
+from repro.core.hardware import resolve_platform
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import abstract_cache
 from repro.models.params import init_params
@@ -79,6 +82,7 @@ from repro.vortex import CompiledOp, Engine, EngineConfig, pow2_bucket
 
 __all__ = [
     "VortexServer",
+    "chain_gemm_sigs",
     "Request",
     "KVBucketPool",
     "RequestError",
@@ -86,6 +90,22 @@ __all__ = [
     "DeadlineExceeded",
     "CacheOverflowError",
 ]
+
+
+def chain_gemm_sigs(cfg) -> list[tuple[int, int]]:
+    """Every (K, N) GEMM signature the chained prefill dispatches for
+    ``cfg``: q/k/v/o projections, the MLP pair, and the LM head."""
+    d, hd = cfg.d_model, cfg.resolved_head_dim
+    sigs = {
+        (d, cfg.n_heads * hd),        # wq
+        (d, cfg.n_kv_heads * hd),     # wk / wv
+        (cfg.n_heads * hd, d),        # wo
+        (d, cfg.vocab_padded),        # lm head
+    }
+    if any(spec.mlp == "dense" for spec in cfg.pattern):
+        sigs.add((d, cfg.d_ff))       # w_in / w_gate
+        sigs.add((cfg.d_ff, d))       # w_out
+    return sorted(sigs)
 
 
 class CacheOverflowError(ValueError):
@@ -270,11 +290,17 @@ class VortexServer:
         self.params = init_params(cfg, jax.random.PRNGKey(seed))
         self.max_cache = max_cache
         if engine is None:
-            # The lattice is built for the TARGET hardware (TPU v5e): its
-            # native sublane granularity (16) is what quantizes the bucket
-            # set — on the CPU host the same buckets are used so
-            # executables dedupe the same way they would on the pod.
-            engine = Engine(EngineConfig(hardware="tpu_v5e", backends=("mxu",)))
+            # The lattice is built for the attached chip (an unknown TPU
+            # kind raises); on the CPU the server builds the v5e lattice,
+            # so its native sublane granularity (16) quantizes the same
+            # bucket set there.  impl is derived from the platform:
+            # compiled Pallas kernels on the TPU, XLA executables on the CPU.
+            platform = resolve_platform()
+            engine = Engine(EngineConfig(
+                hardware=(platform.hardware.name if platform.native_pallas
+                          else "tpu_v5e"),
+                backends=("mxu",),
+            ))
         self.engine = engine
         # The token dim's bucket source: the model's GEMM signature
         # (N/K = d_model); the selector's M-buckets become our seq buckets.
@@ -317,6 +343,7 @@ class VortexServer:
         # Lazy-chain prefill state: per-(bp, sp) alignment verdicts, the
         # unstacked per-layer params in scan order, and the head matrix.
         self._chain_aligned_cache: dict[tuple[int, int], bool] = {}
+        self._attn_aligned_cache: dict[int, bool] = {}
         self._chain_layer_cache: list | None = None
         self._head_cache: jax.Array | None = None
         # Per-token decode accounting (the padding-free decode contract):
@@ -368,7 +395,7 @@ class VortexServer:
         buckets plus their doubling-growth chains."""
         m_max = self.max_cache if m_max is None else min(m_max, self.max_cache)
         out: set[int] = set()
-        for sp in self.seq_buckets(m_max):
+        for sp in self.prefill_seq_buckets(m_max):
             kvb = self.kv_bucket(sp)
             out.add(kvb)
             limit = min(sp + max(max_new, 0), self.max_cache)
@@ -381,10 +408,16 @@ class VortexServer:
 
     def _make_batch(self, bp: int, sp: int, tokens: np.ndarray | None = None):
         toks = np.zeros((bp, sp), np.int32)
+        s = sp
         if tokens is not None:
             b, s = tokens.shape
             toks[:b, :s] = tokens
-        batch = {"tokens": jnp.asarray(toks)}
+        # ``last``: the last REAL prompt position, whose logits predict the
+        # first new token (the rows past it are bucket pad).
+        batch = {
+            "tokens": jnp.asarray(toks),
+            "last": jnp.asarray(s - 1, jnp.int32),
+        }
         if self.cfg.vision_prefix:
             batch["vision_embeds"] = jnp.zeros(
                 (bp, self.cfg.vision_prefix, self.cfg.d_model),
@@ -579,67 +612,79 @@ class VortexServer:
             for spec in cfg.pattern
         )
 
-    def _chain_gemm_sigs(self) -> list[tuple[int, int]]:
-        """Every (K, N) GEMM signature the chained prefill dispatches:
-        q/k/v/o projections, the MLP pair, and the LM head."""
-        cfg = self.cfg
-        d, hd = cfg.d_model, cfg.resolved_head_dim
-        sigs = {
-            (d, cfg.n_heads * hd),        # wq
-            (d, cfg.n_kv_heads * hd),     # wk / wv
-            (cfg.n_heads * hd, d),        # wo
-            (d, cfg.vocab_padded),        # lm head
-        }
-        if any(spec.mlp == "dense" for spec in cfg.pattern):
-            sigs.add((d, cfg.d_ff))       # w_in / w_gate
-            sigs.add((cfg.d_ff, d))       # w_out
-        return sorted(sigs)
+    def _attn_aligned(self, sp: int) -> bool:
+        """True when prefill attention at sequence bucket ``sp`` runs on its
+        own bucket: the attention bucket at sp is (sp, hd, sp) for every
+        window kind, and the kv cache bucket covering sp is sp itself — so
+        the dispatch traced into a prefill program pads nothing."""
+        hit = self._attn_aligned_cache.get(sp)
+        if hit is None:
+            cfg = self.cfg
+            hd = cfg.resolved_head_dim
+            hit = self.kv_bucket(sp) == sp and all(
+                self.engine.kernel_for(AttentionWorkload(
+                    seq=None, head_dim=hd, causal=True,
+                    window=window, softcap=cfg.attn_softcap,
+                )).select(sp).bucket == (sp, hd, sp)
+                for window in {
+                    spec.window for spec in cfg.pattern
+                    if spec.mixer == "attn"
+                }
+            )
+            self._attn_aligned_cache[sp] = hit
+        return hit
 
     def _chain_aligned(self, bp: int, sp: int) -> bool:
         """True when EVERY dispatch of a (bp, sp) chained prefill lands on
         its own bucket: each chain GEMM's selection at m = bp*sp pads to
-        exactly bp*sp, the attention bucket at sp is (sp, hd, sp), and the
-        kv cache bucket covering sp is sp itself — so handles forward
-        bucket-to-bucket with zero boundary copies end to end."""
+        exactly bp*sp, and attention is aligned at sp (``_attn_aligned``)
+        — so handles forward bucket-to-bucket with zero boundary copies end
+        to end."""
         key = (bp, sp)
         hit = self._chain_aligned_cache.get(key)
         if hit is None:
-            eng, cfg = self.engine, self.cfg
-            hd = cfg.resolved_head_dim
-            m = bp * sp
-            ok = all(
+            eng, m = self.engine, bp * sp
+            hit = self._attn_aligned(sp) and all(
                 eng.kernel_for(
                     GemmWorkload(M=None, N=n, K=k)
                 ).select(m).padded_m == m
-                for k, n in self._chain_gemm_sigs()
+                for k, n in chain_gemm_sigs(self.cfg)
             )
-            if ok:
-                for window in {
-                    spec.window for spec in cfg.pattern
-                    if spec.mixer == "attn"
-                }:
-                    kern = eng.kernel_for(AttentionWorkload(
-                        seq=None, head_dim=hd, causal=True,
-                        window=window, softcap=cfg.attn_softcap,
-                    ))
-                    if kern.select(sp).bucket != (sp, hd, sp):
-                        ok = False
-                        break
-            hit = ok and self.kv_bucket(sp) == sp
             self._chain_aligned_cache[key] = hit
         return hit
 
-    def chain_seq_bucket(self, s: int, bp: int = 1) -> int:
-        """The sequence bucket a chained prefill serves ``s`` at: the first
-        engine bucket >= seq_bucket(s) where the whole chain is aligned
-        (``_chain_aligned``), falling back to seq_bucket(s) when none is —
-        a misaligned chain stays correct, it just pays counted boundary
-        copies."""
+    def _first_aligned(self, s: int, aligned) -> int:
+        """The first engine bucket >= seq_bucket(s) that ``aligned``
+        accepts, or seq_bucket(s) when none is (still correct, it just
+        pays the pads or copies)."""
         base = self.seq_bucket(s)
         for sp in self.seq_buckets():
-            if sp >= base and self._chain_aligned(bp, sp):
+            if sp >= base and aligned(sp):
                 return sp
         return base
+
+    def prefill_seq_bucket(self, s: int) -> int:
+        """The sequence bucket the whole-program prefill serves ``s`` at:
+        the first engine bucket where its attention pads nothing.  The
+        attention lattice's kv block is at least the lane width (128), so
+        prompts shorter than that run every GEMM at the 128 bucket."""
+        return self._first_aligned(s, self._attn_aligned)
+
+    def prefill_seq_buckets(self, m_max: int | None = None) -> list[int]:
+        """Every bucket the whole-program prefill serves prompts up to
+        ``m_max`` at (what ``warmup`` compiles)."""
+        return sorted({
+            self.prefill_seq_bucket(s) for s in self.seq_buckets(m_max)
+        })
+
+    def chain_seq_bucket(self, s: int, bp: int = 1) -> int:
+        """The sequence bucket a chained prefill serves ``s`` at: the first
+        engine bucket where the whole chain is aligned (``_chain_aligned``)
+        — a misaligned chain stays correct, it just pays counted boundary
+        copies."""
+        return self._first_aligned(
+            s, functools.partial(self._chain_aligned, bp)
+        )
 
     def _chain_layers(self) -> list:
         """(spec, params) per layer in scan execution order (group-major),
@@ -686,9 +731,10 @@ class VortexServer:
         vocab mask via ``lazy_map`` — every engine boundary passes a
         LazyBucket, so at a chain-aligned ``sp`` nothing unstages between
         dispatches.  Returns ``(last_logits, cache)`` exactly like the AOT
-        prefill step: last_logits at the padded position sp-1 (the chain's
-        handles are fully valid to the bucket width, reproducing the AOT
-        padded-position semantics), cache leaves kv-bucket shaped.
+        prefill step: last_logits at the last real prompt position
+        ``batch["last"]`` (the chain's handles are fully valid to the bucket
+        width, so that row is read straight from the buffer), cache leaves
+        kv-bucket shaped.
 
         ``eager=True`` runs the IDENTICAL dispatch sequence on plain arrays
         (per-op stage/unstage) — the bit-identity reference the tests and
@@ -748,13 +794,11 @@ class VortexServer:
                 ),
                 logits,
             )
-        # The AOT step returns logits[:, -1] at the PADDED position; the
-        # chain's handle is fully valid to the bucket width, so its buffer
-        # row sp-1 is the same position — read it without forcing a slice.
+        # The chain's handle is fully valid to the bucket width, so the
+        # last real prompt row is read without forcing a slice.
         if isinstance(logits, LazyBucket):
-            last = logits.buffer[:, -1]
-        else:
-            last = logits[:, -1]
+            logits = logits.buffer
+        last = logits[:, batch["last"]]
 
         kvb = self.kv_bucket(sp)
         n_pos = len(cfg.pattern)
@@ -789,7 +833,7 @@ class VortexServer:
         compiled = 0
         bp = 1
         while True:
-            for sp in self.seq_buckets(m_max):
+            for sp in self.prefill_seq_buckets(m_max):
                 if (bp, sp) not in self._prefill_exec:
                     self._prefill_exec_for(bp, sp, self._make_batch(bp, sp))
                     compiled += 1
@@ -836,33 +880,43 @@ class VortexServer:
 
     # -- serving ------------------------------------------------------------
 
-    def generate(self, req: Request) -> np.ndarray:
-        b, s = req.tokens.shape
-        if s + req.max_new - 1 > self.max_cache:
+    def _prefill(self, tokens: np.ndarray):
+        """Prefill a (b, s) prompt through the configured path: the batch
+        bucket, the sequence bucket, the logits predicting the first new
+        token, and the kv-bucket-shaped cache."""
+        b, s = tokens.shape
+        bp = self.batch_bucket(b)
+        if self.prefill == "chained" and self._prefill_chained_supported():
+            sp = self.chain_seq_bucket(s, bp)
+            batch = self._make_batch(bp, sp, tokens)
+            logits, cache = self.prefill_chained(bp, sp, batch)
+            self.stats["chained_prefills"] += 1
+        else:
+            sp = self.prefill_seq_bucket(s)
+            batch = self._make_batch(bp, sp, tokens)
+            logits, cache = self._prefill_exec_for(bp, sp, batch)(
+                self.params, batch
+            )
+        return bp, sp, logits, cache
+
+    def _serve(self, tokens: np.ndarray, steps: int, forced=None):
+        """Yield ``(logits, greedy)`` for the prefill, then for ``steps``
+        decode launches: logits ``(bp, vocab)`` and their argmax ``(bp,)``.
+        Step i feeds ``forced[:, i]`` when given (teacher forcing), else the
+        previous greedy token."""
+        b, s = tokens.shape
+        if s + steps > self.max_cache:
             # Refuse loudly BEFORE any prefill work: past the cap the
             # cache cannot grow, the in-program dynamic_update_slice would
             # clamp its start and silently stomp the last KV row —
             # corrupted logits with no signal.  Same typed error as the
             # scheduler's admission-time rejection (launch/scheduler.py).
             raise CacheOverflowError(
-                f"prompt_len {s} + max_new {req.max_new} needs "
-                f"{s + req.max_new - 1} cache rows > max_cache "
+                f"prompt_len {s} + max_new {steps + 1} needs "
+                f"{s + steps} cache rows > max_cache "
                 f"{self.max_cache}; raise max_cache or shorten the request"
             )
-        bp = self.batch_bucket(b)
-        if self.prefill == "chained" and self._prefill_chained_supported():
-            sp = self.chain_seq_bucket(s, bp)
-            batch = self._make_batch(bp, sp, req.tokens)
-            logits, cache = self.prefill_chained(bp, sp, batch)
-            self.stats["chained_prefills"] += 1
-        else:
-            sp = self.seq_bucket(s)
-            batch = self._make_batch(bp, sp, req.tokens)
-            logits, cache = self._prefill_exec_for(bp, sp, batch)(
-                self.params, batch
-            )
-        out = [np.asarray(jnp.argmax(logits, -1))]
-        tok = jnp.asarray(out[-1][:, None])
+        bp, sp, logits, cache = self._prefill(tokens)
         pos = s - 1
         kvb = self.kv_bucket(sp)  # the prefill-emitted cache length
         st = self.decode_stats
@@ -871,7 +925,14 @@ class VortexServer:
         # mid-decode, so the pool's lease ledger can never leak.
         self.adopt_cache(cache)
         try:
-            for i in range(req.max_new - 1):
+            greedy = jnp.argmax(logits, -1)
+            yield logits, greedy
+            for i in range(steps):
+                if forced is None:
+                    tok = greedy
+                else:
+                    tok = np.zeros((bp,), np.int32)
+                    tok[:b] = forced[:, i]
                 pos += 1
                 needed = pos + 1  # rows the cache must hold after this step
                 st.calls += 1
@@ -882,15 +943,40 @@ class VortexServer:
                 else:
                     st.aligned_calls += 1
                 logits, cache = self._decode_exec_for(bp, kvb)(
-                    self.params, cache, tok, jnp.asarray(pos, jnp.int32)
+                    self.params, cache, jnp.asarray(tok)[:, None],
+                    jnp.asarray(pos, jnp.int32),
                 )
                 st.launches += 1
-                nxt = jnp.argmax(logits, -1)
-                out.append(np.asarray(nxt))
-                tok = nxt[:, None]
+                greedy = jnp.argmax(logits, -1)
+                yield logits, greedy
         finally:
             self.release_cache(cache)
+
+    def generate(self, req: Request) -> np.ndarray:
+        """Greedy decode: ``(b, max_new)`` tokens for a ``(b, s)`` prompt."""
+        b = req.tokens.shape[0]
+        out = [
+            np.asarray(greedy)
+            for _, greedy in self._serve(req.tokens, req.max_new - 1)
+        ]
         return np.stack(out, 1)[:b]  # (b, max_new)
+
+    def score(self, tokens: np.ndarray, n_prompt: int) -> np.ndarray:
+        """Teacher-forced logits along a given sequence: prefill
+        ``tokens[:, :n_prompt]``, then feed each following token through
+        the decode programs.  Returns ``(b, T - n_prompt + 1, vocab)`` f32:
+        row j holds the logits predicting token ``n_prompt + j`` — what a
+        full forward over ``tokens`` gives at position ``n_prompt - 1 + j``.
+        """
+        b = tokens.shape[0]
+        out = [
+            np.asarray(logits[:b], np.float32)
+            for logits, _ in self._serve(
+                tokens[:, :n_prompt], tokens.shape[1] - n_prompt,
+                forced=tokens[:, n_prompt:],
+            )
+        ]
+        return np.stack(out, 1)
 
 
 def main() -> None:
@@ -906,6 +992,7 @@ def main() -> None:
     )
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = make_host_mesh()
     server = VortexServer(cfg, mesh, max_cache=256)
@@ -957,7 +1044,8 @@ def main() -> None:
             f"engine/{kind}: launches={d['launches']} "
             f"stage_copies={d['stage_copies']} "
             f"unstage_copies={d['unstage_copies']} "
-            f"padded={d['padded_calls']} traced={d['traced_calls']}"
+            f"padded={d['padded_calls']} traced={d['traced_calls']} "
+            f"fallbacks={d['fallbacks']} quarantined={d['quarantined']}"
         )
 
 

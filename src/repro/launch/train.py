@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import Prefetcher, SyntheticLMDataset
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models.params import count_params, init_params, param_pspecs
 from repro.models.partitioning import make_rules, spec_tree_to_shardings
@@ -67,6 +68,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_smoke_config(args.arch) if args.smoke else get_config(args.arch)
     mesh = (
         make_production_mesh() if args.mesh == "prod" else make_host_mesh()

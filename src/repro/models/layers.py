@@ -293,7 +293,6 @@ def flash_decode_sharded(
     Returns (out, k_cache', v_cache').
     """
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     mesh = rules.mesh
     seq_ax = rules.rules.get("seq")
@@ -345,13 +344,13 @@ def flash_decode_sharded(
         out = o_glob / jnp.maximum(l_glob, 1e-30)[..., None]
         return out.reshape(-1, hq, 1, dv).astype(q_.dtype), kc, vc
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(flat_spec, cache_spec, cache_spec, flat_spec, flat_spec,
                   P()),
         out_specs=(flat_spec, cache_spec, cache_spec),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(q, k_cache, v_cache, k_new, v_new, pos)
 

@@ -147,7 +147,10 @@ def make_prefill_step(cfg: ModelConfig, rules: AxisRules, cache_len: int):
             cfg, rules, params, batch["tokens"], mode="prefill",
             cache_len=cache_len, **extras,
         )
-        return logits[:, -1], cache
+        # The logits of the last real prompt position (``batch["last"]``,
+        # when the prompt is padded to a bucket) predict the first token.
+        last = batch.get("last", logits.shape[1] - 1)
+        return jax.lax.dynamic_index_in_dim(logits, last, 1, False), cache
 
     return prefill_step
 

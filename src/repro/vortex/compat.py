@@ -44,14 +44,12 @@ class VortexEngine(Engine):
         backends: tuple[str, ...] | None = None,
         impl: str = "xla",
         num_cores: int = 1,
-        interpret: bool = True,
     ):
         super().__init__(
             EngineConfig(
                 hardware=hardware,
                 backends=backends,
                 impl=impl,
-                interpret=interpret,
                 num_cores=num_cores,
                 empirical_levels=empirical_levels,
             ),

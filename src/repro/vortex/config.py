@@ -21,11 +21,17 @@ class EngineConfig:
     * ``hardware`` — a :func:`repro.core.hardware.get_hardware` name; the
       lattice is generated for THIS target even when executing on a host
       (serving uses ``tpu_v5e`` buckets on the CPU so executables dedupe
-      the same way they would on the pod).
+      the same way they would on the chip).  None derives it when the
+      Engine is built: the attached TPU's spec
+      (:func:`~repro.core.hardware.resolve_platform`), ``host_cpu`` on
+      the CPU.
     * ``backends`` — compute backends to score (None = all the hardware
       declares, e.g. MXU + VPU; the selector picks per shape, Fig. 16).
     * ``impl`` — executable implementation: ``"xla"`` (flat JAX ops) or
-      ``"pallas"`` (Vortex-tiled kernels; ``interpret`` runs them off-TPU).
+      ``"pallas"`` (Vortex-tiled kernels).  None derives it from the
+      platform when the :class:`~repro.vortex.Engine` is built: Pallas
+      compiled natively on a TPU, XLA on the CPU.  Pallas asked for on the
+      CPU runs in interpret mode; a TPU never interprets.
     * ``empirical_levels`` — hierarchy levels the hybrid analyzer measures
       empirically (None = paper defaults, Table 7: level 0 on CPU, levels
       0-1 on accelerator-class hardware; ``()`` = fully analytical).
@@ -68,10 +74,9 @@ class EngineConfig:
       quarantine in-memory only (tests, hermetic runs).
     """
 
-    hardware: str = "host_cpu"
+    hardware: str | None = None
     backends: tuple[str, ...] | None = None
-    impl: str = "xla"
-    interpret: bool = True
+    impl: str | None = None
     num_cores: int = 1
     empirical_levels: tuple[int, ...] | None = None
     table_m_max: int = 4096
@@ -87,6 +92,10 @@ class EngineConfig:
     denylist_persist: bool = True
 
     def __post_init__(self) -> None:
+        if self.impl not in (None, "xla", "pallas"):
+            raise ValueError(
+                f"impl must be 'xla', 'pallas' or None, got {self.impl!r}"
+            )
         if self.max_kernel_retries < 0:
             raise ValueError(
                 f"max_kernel_retries must be >= 0, "

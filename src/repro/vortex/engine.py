@@ -25,7 +25,7 @@ from repro.core.analyzer import (
     WallClockProfiler,
 )
 from repro.core.engine import OfflineStats, VortexKernel
-from repro.core.hardware import get_hardware
+from repro.core.hardware import get_hardware, resolve_platform
 from repro.core.workloads import WORKLOADS, Workload, make_workload
 from repro.vortex.config import EngineConfig
 from repro.vortex.handle import CompiledOp
@@ -75,6 +75,15 @@ class Engine:
                 config = EngineConfig(hardware=config)
             if overrides:
                 config = dataclasses.replace(config, **overrides)
+        if config.hardware is None or config.impl is None:
+            platform = resolve_platform()
+            config = dataclasses.replace(
+                config,
+                hardware=config.hardware or platform.hardware.name,
+                impl=config.impl or (
+                    "pallas" if platform.native_pallas else "xla"
+                ),
+            )
         self.config = config
         self._hw = get_hardware(config.hardware)
         if profiler is None:
@@ -170,7 +179,6 @@ class Engine:
                         backends=cfg.backends,
                         num_cores=cfg.num_cores,
                         impl=cfg.impl,
-                        interpret=cfg.interpret,
                         scored_cache=self._scored_cache,
                         table_m_max=cfg.table_m_max,
                         table_extend_limit=cfg.table_extend_limit,
@@ -205,7 +213,6 @@ class Engine:
                 self._hw,
                 cfg.backends or tuple(self._hw.backends),
                 cfg.impl,
-                cfg.interpret,
                 cache_dir=cfg.calibration_cache_dir,
             )
         return self._denylist

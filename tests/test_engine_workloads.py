@@ -32,11 +32,9 @@ def _arr(shape):
 
 @pytest.fixture(scope="module", params=["xla", "pallas"])
 def engine(request):
-    # pallas runs in interpret mode on this host; empirical_levels=() keeps
+    # pallas runs in interpret mode on the CPU; empirical_levels=() keeps
     # the offline stage fast and deterministic.
-    return Engine(
-        "host_cpu", empirical_levels=(), impl=request.param, interpret=True
-    )
+    return Engine("host_cpu", empirical_levels=(), impl=request.param)
 
 
 # ---------------------------------------------------------------------------

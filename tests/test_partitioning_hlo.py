@@ -15,17 +15,9 @@ from repro.roofline.memory import tree_device_bytes
 from repro.train.step import serve_input_specs, train_input_specs
 
 
-def _abstract_mesh(shape, axes):
-    try:
-        return jax.sharding.AbstractMesh(shape, axes)  # jax >= 0.5
-    except TypeError:
-        # jax 0.4.x signature: AbstractMesh(((name, size), ...)).
-        return jax.sharding.AbstractMesh(tuple(zip(axes, shape)))
-
-
 def _abstract_rules(shape=(16, 16), axes=("data", "model"),
                     fsdp=False, n_heads=16, n_kv_heads=8):
-    mesh = _abstract_mesh(shape, axes)
+    mesh = jax.sharding.AbstractMesh(shape, axes)
     return make_rules(
         mesh, fsdp=fsdp, n_heads=n_heads, n_kv_heads=n_kv_heads
     )

@@ -38,9 +38,7 @@ def _arr(shape):
 
 @pytest.fixture(scope="module", params=["xla", "pallas"])
 def engine(request):
-    return Engine(
-        "host_cpu", empirical_levels=(), impl=request.param, interpret=True
-    )
+    return Engine("host_cpu", empirical_levels=(), impl=request.param)
 
 
 # One entry per registered workload kind: (workload params for
@@ -289,7 +287,7 @@ def test_selection_deviation_raises_instead_of_clamping():
     sel = kern.select(64)
     bad = dataclasses.replace(sel, padded_m=sel.padded_m + 1)
     with pytest.raises(SelectionDeviationError, match="not a multiple"):
-        kern.workload.build_executable(bad, impl="pallas", interpret=True)
+        kern.workload.build_executable(bad, impl="pallas")
 
     wl = AttentionWorkload(seq=None, head_dim=32)
     akern = eng.kernel_for(wl)
@@ -298,7 +296,7 @@ def test_selection_deviation_raises_instead_of_clamping():
         asel, bucket=(asel.bucket[0] + 1,) + asel.bucket[1:]
     )
     with pytest.raises(SelectionDeviationError, match="not a multiple"):
-        wl.build_executable(abad, impl="pallas", interpret=True)
+        wl.build_executable(abad, impl="pallas")
 
 
 def test_conv_stage_view_feeds_the_gemm_bucket():

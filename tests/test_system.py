@@ -118,17 +118,22 @@ def test_dynamic_serving_end_to_end(mesh):
     from repro.launch.serve import Request, VortexServer
 
     cfg = get_smoke_config("paper-gpt2-124m")
-    server = VortexServer(cfg, mesh, max_cache=128)
+    server = VortexServer(cfg, mesh, max_cache=256)
     rng = np.random.default_rng(0)
-    shapes = [(1, 5), (2, 9), (2, 12), (1, 14), (3, 30), (4, 60)]
+    shapes = [
+        (1, 5), (2, 9), (2, 12), (1, 14), (3, 30), (4, 60), (1, 130),
+        (2, 200),
+    ]
     for (b, s) in shapes:
         out = server.generate(Request(
             tokens=rng.integers(0, cfg.vocab, (b, s)).astype(np.int32),
             max_new=2,
         ))
         assert out.shape == (b, 2)
-    # 6 distinct request shapes must share a smaller bucket set.
+    # Distinct request shapes must share a smaller bucket set, and prompts
+    # past the first attention-aligned bucket get a bucket of their own.
     assert server.stats["prefill_compiles"] < len(shapes)
+    assert len({sp for _, sp in server._prefill_exec}) > 1
 
 
 def test_server_buckets_are_engine_selector_buckets(mesh):

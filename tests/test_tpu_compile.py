@@ -1,0 +1,227 @@
+"""The main path's Pallas kernels compile for a v5e chip at real widths.
+
+Interpret mode checks neither VMEM nor tiling, so these tests hand the
+kernels to the TPU compiler for a described (unattached) ``v5e:2x2``
+topology: shapes only, nothing runs.  The tiles are the ones the
+``tpu_v5e`` lattice selects for ``paper-gpt2-124m`` (GEMMs, prefill and
+decode attention) and for a ``granite-moe-1b-a400m`` expert FFN
+(grouped GEMM), at the VMEM limit the engine passes.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may hold the TPU library, and every test worker imports
+this file.  Where it cannot be described, the compile tests skip.
+"""
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.granite_moe_1b import CONFIG as GRANITE
+from repro.core.workloads import (
+    AttentionWorkload,
+    DecodeAttentionWorkload,
+    GemmWorkload,
+    GroupedGemmWorkload,
+)
+from repro.kernels.attention import flash_attention
+from repro.kernels.gemm import vortex_gemm
+from repro.kernels.grouped_gemm import vortex_grouped_gemm
+from repro.launch.serve import chain_gemm_sigs
+from repro.models.registry import get_config
+from repro.vortex import Engine, EngineConfig
+
+GPT2 = get_config("paper-gpt2-124m")
+D, HD, H = GPT2.d_model, GPT2.resolved_head_dim, GPT2.n_heads
+FFN_UP = (D, GPT2.d_ff)
+LM_HEAD = (D, GPT2.vocab_padded)
+EXPERT_UP = (GRANITE.d_model, GRANITE.moe.d_ff_expert)
+EXPERT_DOWN = (GRANITE.moe.d_ff_expert, GRANITE.d_model)
+E = GRANITE.moe.num_experts
+
+
+@pytest.fixture(scope="module")
+def engine():
+    return Engine(EngineConfig(
+        hardware="tpu_v5e", backends=("mxu",), impl="pallas",
+        empirical_levels=(), denylist_persist=False,
+    ))
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(one_chip, fn, *shapes):
+    """Compile ``fn`` for one described chip from (shape, dtype) pairs and
+    return the compiled HLO text (raises what the chip's compiler raises)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("m", [16, 1024, 2048, 8192])
+@pytest.mark.parametrize("sig", [FFN_UP, LM_HEAD], ids=["ffn_up", "lm_head"])
+def test_selected_gemm_tile_compiles(engine, one_chip, sig, m):
+    k, n = sig
+    kern = engine.kernel_for(GemmWorkload(M=None, N=n, K=k))
+    sel = kern.select(m)
+    bm, bn, bk = sel.strategy.l1
+
+    def fn(a, b, m_true):
+        return vortex_gemm(
+            a, b, m_true, block_m=bm, block_n=bn, block_k=bk,
+            interpret=False, vmem_limit_bytes=kern.vmem_limit_bytes,
+        )
+
+    hlo = _compile(
+        one_chip, fn, ((sel.padded_m, k), jnp.bfloat16),
+        ((k, n), jnp.bfloat16), ((), jnp.int32),
+    )
+    assert "tpu_custom_call" in hlo
+
+
+def test_prefill_attention_compiles_at_full_context(engine, one_chip):
+    kern = engine.kernel_for(AttentionWorkload(seq=None, head_dim=HD))
+    sel = kern.select(1024)
+    pq, _, pkv = sel.bucket
+    bq, _, bk = sel.strategy.l1
+
+    def fn(q, k, v, kv_len):
+        return flash_attention(
+            q, k, v, kv_len, block_q=bq, block_k=bk, causal=True,
+            interpret=False, vmem_limit_bytes=kern.vmem_limit_bytes,
+        )
+
+    hlo = _compile(
+        one_chip, fn, ((1, H, pq, HD), jnp.bfloat16),
+        ((1, H, pkv, HD), jnp.bfloat16), ((1, H, pkv, HD), jnp.bfloat16),
+        ((), jnp.int32),
+    )
+    assert "tpu_custom_call" in hlo
+
+
+def test_decode_attention_compiles_with_per_row_kv_len(engine, one_chip):
+    kern = engine.kernel_for(
+        DecodeAttentionWorkload(seq=None, head_dim=HD)
+    )
+    sel = kern.select(1024)
+    kvb, bk = sel.bucket[2], sel.strategy.l1[2]
+    b = 8
+
+    def fn(q, k, v, kv_len):
+        return flash_attention(
+            q, k, v, kv_len, q_offset=kv_len - 1, block_q=1, block_k=bk,
+            causal=False, interpret=False,
+            vmem_limit_bytes=kern.vmem_limit_bytes,
+        )
+
+    hlo = _compile(
+        one_chip, fn, ((b, H, 1, HD), jnp.bfloat16),
+        ((b, H, kvb, HD), jnp.bfloat16), ((b, H, kvb, HD), jnp.bfloat16),
+        ((b,), jnp.int32),
+    )
+    assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize(
+    "sig", [EXPERT_UP, EXPERT_DOWN], ids=["expert_up", "expert_down"]
+)
+def test_grouped_gemm_compiles_at_granite_widths(engine, one_chip, sig):
+    k, n = sig
+    kern = engine.kernel_for(
+        GroupedGemmWorkload(C=None, G=E, E=E, N=n, K=k)
+    )
+    sel = kern.select(200)  # capacity rows per expert
+    bm, bn, bk = sel.strategy.l1
+
+    def fn(x, w, counts):
+        return vortex_grouped_gemm(
+            x, w, counts, block_m=bm, block_n=bn, block_k=bk,
+            interpret=False, vmem_limit_bytes=kern.vmem_limit_bytes,
+        )
+
+    hlo = _compile(
+        one_chip, fn, ((E, sel.padded_m, k), jnp.bfloat16),
+        ((E, k, n), jnp.bfloat16), ((E,), jnp.int32),
+    )
+    assert "tpu_custom_call" in hlo
+
+
+def _pallas_calls(jaxpr):
+    """Every pallas_call equation in ``jaxpr``, nested ones too."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn)
+        for p in eqn.params.values():
+            inner = getattr(p, "jaxpr", None)
+            if inner is not None:
+                out += _pallas_calls(getattr(inner, "jaxpr", inner))
+    return out
+
+
+def _vmem_allocated(eqn, dtype_bytes):
+    """VMEM bytes a pallas_call's own specs allocate: two buffers of each
+    VMEM block (in the workload's element size) plus the scratch."""
+    gm = eqn.params["grid_mapping"]
+    blocks = sum(
+        math.prod(bm.block_aval.inner_aval.shape)
+        for bm in gm.block_mappings
+        if bm.block_aval.memory_space != pltpu.SMEM
+    )
+    scratch = sum(
+        math.prod(a.inner_aval.shape) * a.inner_aval.dtype.itemsize
+        for a in gm.scratch_avals
+    )
+    return 2 * blocks * dtype_bytes + scratch
+
+
+def test_selectable_tiles_fit_the_vmem_limit_passed_to_the_compiler(engine):
+    """For the model's GEMM and attention signatures: every lattice tile
+    counts no more than the VMEM limit; the count covers what the kernel's
+    specs allocate for the tiles the selector picks; and the built
+    executable hands that limit to the compiler."""
+    gemms = [GemmWorkload(M=None, N=n, K=k) for k, n in chain_gemm_sigs(GPT2)]
+    grouped = [
+        GroupedGemmWorkload(C=None, G=E, E=E, N=n, K=k)
+        for k, n in (EXPERT_UP, EXPERT_DOWN)
+    ]
+    attn = [AttentionWorkload(seq=None, head_dim=HD),
+            DecodeAttentionWorkload(seq=None, head_dim=HD)]
+    for wl in gemms + grouped + attn:
+        kern = engine.kernel_for(wl)
+        limit = kern.vmem_limit_bytes
+        for scored in kern.selector.scored.values():
+            for tile in scored.l1_tiles:
+                assert wl.l1_tile_bytes(tuple(tile)) <= limit, (wl, tile)
+        for m in (1, 300, 1024):
+            sel = kern.select(m)
+            fn = wl.build_executable(
+                sel, impl="pallas", vmem_limit_bytes=limit
+            )
+            args = [
+                jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a))
+                for a in wl.example_args(sel)
+            ]
+            (eqn,) = _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+            mosaic = eqn.params["compiler_params"]["mosaic_tpu"]
+            assert mosaic.vmem_limit_bytes == limit, wl
+            counted = wl.l1_tile_bytes(sel.strategy.l1)
+            assert _vmem_allocated(eqn, wl.dtype_bytes) <= counted, (wl, m)

@@ -5,16 +5,20 @@ handles, EngineConfig, precompile diagnostics, and the deprecation shims'
 parity contract (bit-identical outputs, identical cache keys)."""
 import dataclasses
 import threading
+import types
 from typing import ClassVar
 
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro import vortex
 from repro.core import GemmWorkload, PrecompileError, AttentionWorkload
+from repro.core.hardware import resolve_platform
 from repro.core.workloads import WORKLOADS
+from repro.kernels.gemm import interpret_pallas
 from repro.kernels.ref import ref_attention, ref_conv2d, ref_gemm
 from repro.vortex import (
     CompiledOp,
@@ -50,9 +54,9 @@ def test_registered_toy_workload_served_with_no_engine_edits():
 
         kind: ClassVar[str] = "doubled_gemm_toy"
 
-        def build_executable(self, sel, *, impl, interpret):
+        def build_executable(self, sel, *, impl, vmem_limit_bytes=None):
             inner = GemmWorkload.build_executable(
-                self, sel, impl=impl, interpret=interpret
+                self, sel, impl=impl, vmem_limit_bytes=vmem_limit_bytes
             )
 
             # The staging contract: the fused executable takes the bucket
@@ -198,6 +202,40 @@ def test_engine_config_is_frozen_and_overridable():
     eng = Engine(cfg, empirical_levels=())
     assert eng.config.hardware == "tpu_v5e"
     assert eng.config.empirical_levels == ()
+
+
+def test_engine_derives_hardware_and_impl_from_the_platform():
+    eng = Engine(empirical_levels=())
+    assert (eng.config.hardware, eng.config.impl) == ("host_cpu", "xla")
+    assert Engine("tpu_v5e", empirical_levels=()).config.impl == "xla"
+    with pytest.raises(ValueError, match="impl"):
+        EngineConfig(impl="mosaic")
+
+
+@pytest.mark.parametrize("platform,kind,expect", [
+    ("cpu", "cpu", ("host_cpu", False)),
+    ("tpu", "TPU v5 lite", ("tpu_v5e", True)),
+    ("tpu", "TPU v4", None),
+    ("gpu", "NVIDIA H100", None),
+])
+def test_resolve_platform_maps_known_kinds_only(platform, kind, expect):
+    dev = types.SimpleNamespace(platform=platform, device_kind=kind)
+    if expect is None:
+        with pytest.raises(ValueError, match=kind):
+            resolve_platform(dev)
+    else:
+        got = resolve_platform(dev)
+        assert (got.hardware.name, got.native_pallas) == expect
+
+
+def test_pallas_interprets_only_on_the_cpu(monkeypatch):
+    assert interpret_pallas() is True
+    assert interpret_pallas(False) is False
+    tpu = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+    monkeypatch.setattr(jax, "devices", lambda *a: [tpu])
+    assert interpret_pallas() is False
+    with pytest.raises(ValueError, match="interpret"):
+        interpret_pallas(True)
 
 
 def test_config_table_limits_reach_the_selector():
