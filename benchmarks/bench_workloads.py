@@ -377,8 +377,19 @@ def _bench_continuous_batching(smoke: bool) -> dict:
             server.generate(req)
         return time.perf_counter() - t0
 
-    def timed_sched(batch_rows: int) -> tuple[float, dict]:
+    def counts() -> tuple[int, int]:
+        """(decode programs looked up, engine calls run padded) so far."""
+        st = server.stats
+        padded = sum(
+            s.get("padded_calls", 0)
+            for s in server.engine_dispatch_stats().values()
+        )
+        return st["decode_compiles"] + st["decode_bucket_hits"], padded
+
+    def timed_sched(batch_rows: int) -> tuple[float, int, int, int]:
+        """(wall, batched steps, decode launches, padded calls)."""
         sched = ContinuousScheduler(server, batch_rows=batch_rows)
+        launches0, padded0 = counts()
         t0 = time.perf_counter()
         for req in reqs:
             sched.submit(req)
@@ -386,7 +397,9 @@ def _bench_continuous_batching(smoke: bool) -> dict:
         wall = time.perf_counter() - t0
         assert len(res) == len(reqs)
         sched.close()
-        return wall, sched.stats
+        launches, padded = counts()
+        return (wall, sched.stats["steps"], launches - launches0,
+                padded - padded0)
 
     timed_serial()  # warm every prefill/decode executable
     serial_wall = timed_serial()
@@ -399,15 +412,15 @@ def _bench_continuous_batching(smoke: bool) -> dict:
     worst_lps, padded = 0.0, 0
     for c in (1, 4, 16):
         timed_sched(c)  # warm the (c, kvb) mixed-progress programs
-        wall, stats = timed_sched(c)
-        lps = stats["launches"] / max(stats["steps"], 1)
+        wall, steps, launches, padded_c = timed_sched(c)
+        lps = launches / max(steps, 1)
         worst_lps = max(worst_lps, lps)
-        padded += stats["padded_calls"]
+        padded += padded_c
         out["concurrency"][str(c)] = {
             "tokens_per_s": total_tokens / wall,
-            "batched_steps": stats["steps"],
+            "batched_steps": steps,
             "launches_per_batched_step": lps,
-            "padded_calls": stats["padded_calls"],
+            "padded_calls": padded_c,
         }
     out["launches_per_batched_step"] = worst_lps
     out["padded_calls"] = padded

@@ -263,7 +263,6 @@ def main() -> int:
         check(leases == 0, f"kv pool leaked {leases} leases")
         print(f"scheduler: {len(results)} requests, "
               f"steps={sched.stats['steps']} "
-              f"launches={sched.stats['launches']} "
               f"request_errors={sched.stats['request_errors']}")
 
     with phase("logits_vs_reference", times):

@@ -27,10 +27,17 @@ dimension instead:
     bucket the shared cache grows through the pool
     (``VortexServer._grow_cache``) exactly like the serial path.
 
-Step-granular contract (asserted by tests/test_scheduler.py and gated in
-the bench): one AOT launch per batched decode step, zero padded calls,
-and per-request outputs token-identical to serial ``generate()`` on the
-same server.
+Step-granular contract (asserted by tests/test_scheduler.py): one AOT
+launch per batched decode step, and per-request outputs token-identical
+to serial ``generate()`` on the same server.
+
+Tracing: each step opens bare-named ``jax.profiler`` spans (``sched.step``,
+``sched.admit``, ``sched.prefill``, ``sched.first_token``,
+``sched.slot_copy``, ``sched.grow``, ``sched.decode``, ``sched.readback``,
+``sched.emit``) and adds the same ``perf_counter`` stamps to the step's
+entry in ``step_positions`` (see ``ContinuousScheduler``), so the trace and
+the record agree.  With no profiler running a span costs a microsecond
+or two; no span adds a device sync or a transfer.
 
 Failure domains (DESIGN.md §11): a fault while admitting, growing, or
 decoding resolves to a typed per-request error — ``drain()`` returns
@@ -72,6 +79,9 @@ from repro.vortex import pow2_bucket
 
 __all__ = ["ContinuousScheduler", "batched_decode_supported"]
 
+# The phases whose time the host spends waiting on the device.
+_BLOCKING = ("sched.first_token", "sched.readback")
+
 
 def batched_decode_supported(cfg) -> bool:
     """True when the mixed-progress batched decode serves this arch: all
@@ -83,6 +93,30 @@ def batched_decode_supported(cfg) -> bool:
     return all(
         spec.mixer == "attn" and not spec.cross_attn for spec in cfg.pattern
     )
+
+
+class _Phase:
+    """One span of a step: a profiler annotation ``name`` (``ids`` as its
+    stats) around the block, and the block's ``perf_counter`` seconds
+    added to ``rec["phases"][name]``.  ``t`` and ``seconds`` are the
+    block's start stamp and length, taken inside the annotation."""
+
+    __slots__ = ("rec", "name", "ann", "t", "seconds")
+
+    def __init__(self, rec: dict, name: str, **ids):
+        self.rec, self.name = rec, name
+        self.ann = jax.profiler.TraceAnnotation(name, **ids)
+
+    def __enter__(self) -> "_Phase":
+        self.ann.__enter__()
+        self.t = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self.t
+        self.ann.__exit__(*exc)
+        phases = self.rec["phases"]
+        phases[self.name] = phases.get(self.name, 0.0) + self.seconds
 
 
 @dataclasses.dataclass
@@ -111,6 +145,18 @@ class ContinuousScheduler:
 
     ``max_queue`` bounds the admission queue (``submit`` raises
     :class:`QueueFullError` at capacity); None = unbounded.
+
+    ``step_positions`` holds one dict per decode launch: ``kvb`` (the
+    bucket it ran at), ``pos`` and ``slots`` (its active rows), ``t0`` and
+    ``t1`` (``perf_counter`` at ``step()`` entry and return), ``phases``
+    (seconds per span name, summed over the step), ``blocked_s`` (seconds
+    in ``sched.first_token`` and ``sched.readback``, the host waiting on
+    the device) and ``admits`` (one dict per admission of the step:
+    ``rid``, ``prompt`` length, prefill ``bucket``, ``admit_s`` and
+    ``queued_s``, admission start minus ``submit()``).  A step that ends
+    without a launch (idle, or every row failed) records nothing, its
+    admissions included; an admission that fails adds its time to
+    ``phases`` and no ``admits`` entry.
     """
 
     def __init__(
@@ -140,16 +186,17 @@ class ContinuousScheduler:
         self._partial: dict[int, tuple[np.ndarray, int]] = {}
         # rid -> (absolute monotonic deadline, the request's deadline_s).
         self._deadlines: dict[int, tuple[float, float]] = {}
+        # rid -> perf_counter at submit(), while the request is queued.
+        self._submitted: dict[int, float] = {}
         self.rows: list[_Row | None] = [None] * self.batch_rows
         self.cache: dict | None = None
         self.kvb = 0
         self.stats = {
-            "steps": 0, "launches": 0, "padded_calls": 0,
-            "admitted": 0, "retired": 0, "calibration_slices": 0,
-            "request_errors": 0, "deadline_expired": 0,
+            "steps": 0, "admitted": 0, "retired": 0,
+            "calibration_slices": 0, "request_errors": 0,
+            "deadline_expired": 0,
         }
-        # Per-step active-row positions (and the bucket they ran at), the
-        # evidence the staggering tests read: one entry per launch.
+        # One record per launch (see the class docstring).
         self.step_positions: list[dict] = []
 
     # -- admission queue ----------------------------------------------------
@@ -186,6 +233,7 @@ class ContinuousScheduler:
             self._next_id += 1
             req = dataclasses.replace(req, request_id=rid)
             self._queue.append(req)
+            self._submitted[rid] = time.perf_counter()
             if req.deadline_s is not None:
                 self._deadlines[rid] = (
                     time.monotonic() + req.deadline_s, req.deadline_s
@@ -222,9 +270,10 @@ class ContinuousScheduler:
         self.cache = cache
         self.kvb = kvb
 
-    def _grow(self, new_kvb: int) -> None:
+    def _grow(self, new_kvb: int, rec: dict) -> None:
         assert self.cache is not None
-        self.cache = self.server._grow_cache(self.cache, new_kvb)
+        with _Phase(rec, "sched.grow", kvb=new_kvb):
+            self.cache = self.server._grow_cache(self.cache, new_kvb)
         self.kvb = new_kvb
 
     def close(self) -> None:
@@ -272,6 +321,7 @@ class ContinuousScheduler:
             rid, stage, f"{type(exc).__name__}: {exc}"
         )
         with self._lock:
+            self._submitted.pop(rid, None)
             self._results[rid] = err
         if isinstance(err, DeadlineExceeded):
             self.stats["deadline_expired"] += 1
@@ -301,47 +351,58 @@ class ContinuousScheduler:
             )
         return bool(expired)
 
-    def _admit(self, req: Request) -> None:
+    def _admit(self, req: Request, submitted: float, rec: dict) -> None:
         """Prefill ONE queued request through the server's serial prefill
         executables and seat its rows: per-row first token from the
         prefill argmax, cache rows copied into free slots, the transient
-        per-request buffers released back to the pool."""
-        if faults.ACTIVE is not None:
-            faults.ACTIVE.check("scheduler_step")
+        per-request buffers released back to the pool.  ``submitted`` is
+        its ``submit()`` stamp; the admission goes into ``rec``."""
         srv = self.server
+        rid = req.request_id
+        assert rid is not None
         b, s = req.tokens.shape
-        bp = srv.batch_bucket(b)
         sp = srv.prefill_seq_bucket(s)
-        batch = srv._make_batch(bp, sp, req.tokens)
-        logits, rcache = srv._prefill_exec_for(bp, sp, batch)(
-            srv.params, batch
-        )
-        srv.adopt_cache(rcache)
-        try:
-            first = np.asarray(jnp.argmax(logits, -1))  # (bp,)
-            kvb_req = srv.kv_bucket(sp)
-            self._ensure_cache(kvb_req)
-            if kvb_req > self.kvb:
-                self._grow(kvb_req)
-            slots = self._free_slots()
-            rid = req.request_id
-            assert rid is not None
-            self._partial[rid] = (
-                np.zeros((b, req.max_new), np.int64), b
-            )
-            for r in range(b):
-                slot = slots[r]
-                self._copy_row(rcache, r, slot)
-                tok = int(first[r])
-                self.rows[slot] = _Row(
-                    rid=rid, req_row=r, pos_next=s,
-                    remaining=req.max_new - 1, last_tok=tok, out=[tok],
-                    max_new=req.max_new, stop=req.stop,
+        with _Phase(
+            rec, "sched.admit", rid=rid, prompt=s, bucket=sp
+        ) as admit:
+            if faults.ACTIVE is not None:
+                faults.ACTIVE.check("scheduler_step")
+            bp = srv.batch_bucket(b)
+            with _Phase(rec, "sched.prefill"):
+                batch = srv._make_batch(bp, sp, req.tokens)
+                logits, rcache = srv._prefill_exec_for(bp, sp, batch)(
+                    srv.params, batch
                 )
-                if req.stop is not None and tok == req.stop:
-                    self.rows[slot].remaining = 0
-        finally:
-            srv.release_cache(rcache)
+            srv.adopt_cache(rcache)
+            try:
+                with _Phase(rec, "sched.first_token"):
+                    first = np.asarray(jnp.argmax(logits, -1))  # (bp,)
+                kvb_req = srv.kv_bucket(sp)
+                with _Phase(rec, "sched.slot_copy"):
+                    self._ensure_cache(kvb_req)
+                    if kvb_req > self.kvb:
+                        self._grow(kvb_req, rec)
+                    slots = self._free_slots()
+                    self._partial[rid] = (
+                        np.zeros((b, req.max_new), np.int64), b
+                    )
+                    for r in range(b):
+                        slot = slots[r]
+                        self._copy_row(rcache, r, slot)
+                        tok = int(first[r])
+                        self.rows[slot] = _Row(
+                            rid=rid, req_row=r, pos_next=s,
+                            remaining=req.max_new - 1, last_tok=tok,
+                            out=[tok], max_new=req.max_new, stop=req.stop,
+                        )
+                        if req.stop is not None and tok == req.stop:
+                            self.rows[slot].remaining = 0
+            finally:
+                srv.release_cache(rcache)
+        rec["admits"].append({
+            "rid": rid, "prompt": s, "bucket": sp,
+            "admit_s": admit.seconds, "queued_s": admit.t - submitted,
+        })
         self.stats["admitted"] += 1
 
     def _retire(self, slot: int) -> None:
@@ -375,6 +436,16 @@ class ContinuousScheduler:
         the rows that shared it.  Nothing propagates out of ``step()`` —
         the loop, the shared cache, and the lease ledger stay serviceable.
         """
+        rec: dict = {"t0": time.perf_counter(), "phases": {}, "admits": []}
+        with _Phase(rec, "sched.step"):
+            worked = self._step(rec)
+        rec["t1"] = time.perf_counter()
+        rec["blocked_s"] = sum(rec["phases"].get(n, 0.0) for n in _BLOCKING)
+        return worked
+
+    def _step(self, rec: dict) -> bool:
+        """The body of ``step()``; ``rec`` joins ``step_positions`` at the
+        launch."""
         srv = self.server
         worked = False
         for slot, row in enumerate(self.rows):
@@ -391,10 +462,12 @@ class ContinuousScheduler:
                     <= len(self._free_slots())
                     else None
                 )
+                if req is not None:
+                    submitted = self._submitted.pop(req.request_id)
             if req is None:
                 break
             try:
-                self._admit(req)
+                self._admit(req, submitted, rec)
             except Exception as exc:
                 assert req.request_id is not None
                 self._fail_request(req.request_id, "admit", exc)
@@ -421,7 +494,7 @@ class ContinuousScheduler:
         needed = max(row.pos_next + 1 for _, row in active)
         if needed > self.kvb and self.kvb < srv.max_cache:
             try:
-                self._grow(srv._grown_kv_bucket(self.kvb, needed))
+                self._grow(srv._grown_kv_bucket(self.kvb, needed), rec)
             except Exception as exc:
                 # Two-phase growth left the shared cache (and every lease)
                 # untouched — fail exactly the rows that no longer fit the
@@ -434,20 +507,23 @@ class ContinuousScheduler:
                     self._fail_request(rid, "grow", exc)
                 return True
 
-        # Free slots decode at pos 0: their k/v row 0 is freshly written
-        # by this very launch (finite), and kv_len = 1 reads only it.
-        tok = np.zeros((self.batch_rows, 1), np.int32)
-        pos = np.zeros((self.batch_rows,), np.int32)
-        for slot, row in active:
-            tok[slot, 0] = row.last_tok
-            pos[slot] = row.pos_next
         try:
-            if faults.ACTIVE is not None:
-                faults.ACTIVE.check("scheduler_step")
-            exe = srv._decode_exec_vec_for(self.batch_rows, self.kvb)
-            logits, self.cache = exe(
-                srv.params, self.cache, jnp.asarray(tok), jnp.asarray(pos)
-            )
+            with _Phase(rec, "sched.decode", rows=len(active), kvb=self.kvb):
+                # Free slots decode at pos 0: their k/v row 0 is freshly
+                # written by this very launch (finite), and kv_len = 1
+                # reads only it.
+                tok = np.zeros((self.batch_rows, 1), np.int32)
+                pos = np.zeros((self.batch_rows,), np.int32)
+                for slot, row in active:
+                    tok[slot, 0] = row.last_tok
+                    pos[slot] = row.pos_next
+                if faults.ACTIVE is not None:
+                    faults.ACTIVE.check("scheduler_step")
+                exe = srv._decode_exec_vec_for(self.batch_rows, self.kvb)
+                logits, self.cache = exe(
+                    srv.params, self.cache, jnp.asarray(tok),
+                    jnp.asarray(pos),
+                )
         except Exception as exc:
             # The launch raised before the cache assignment: the shared
             # leaves are exactly the pre-step state.  Every row that
@@ -456,21 +532,23 @@ class ContinuousScheduler:
                 self._fail_request(rid, "decode", exc)
             return True
         self.stats["steps"] += 1
-        self.stats["launches"] += 1  # the ONE launch this step performed
-        self.step_positions.append({
-            "kvb": self.kvb,
-            "pos": np.asarray([row.pos_next for _, row in active]),
-            "slots": np.asarray([slot for slot, _ in active]),
-        })
-        nxt = np.asarray(jnp.argmax(logits, -1))  # (batch_rows,)
-        for slot, row in active:
-            t = int(nxt[slot])
-            row.out.append(t)
-            row.last_tok = t
-            row.pos_next += 1
-            row.remaining -= 1
-            if row.stop is not None and t == row.stop:
-                row.remaining = 0
+        rec.update(
+            kvb=self.kvb,
+            pos=np.asarray([row.pos_next for _, row in active]),
+            slots=np.asarray([slot for slot, _ in active]),
+        )
+        self.step_positions.append(rec)
+        with _Phase(rec, "sched.readback"):
+            nxt = np.asarray(jnp.argmax(logits, -1))  # (batch_rows,)
+        with _Phase(rec, "sched.emit"):
+            for slot, row in active:
+                t = int(nxt[slot])
+                row.out.append(t)
+                row.last_tok = t
+                row.pos_next += 1
+                row.remaining -= 1
+                if row.stop is not None and t == row.stop:
+                    row.remaining = 0
         return True
 
     def _donate_idle_slice(self) -> None:

@@ -8,10 +8,10 @@ against the serial ``generate()`` path on the SAME server (identical
 params, identical prefill executables): per-request token sequences must
 match exactly.
 
-Structural contract, asserted alongside identity: launches == steps
-(one AOT program per batched step), padded_calls == 0, and the pool's
-lease ledger settles to 0 — on retirement, on ``generate()`` exceptions,
-and after ``close()``.
+Structural contract, asserted alongside identity: one
+``step_positions`` record per batched decode launch (``stats["steps"]``),
+and the pool's lease ledger settles to 0 — on retirement, on
+``generate()`` exceptions, and after ``close()``.
 """
 import threading
 
@@ -51,8 +51,7 @@ def _serial(server, reqs):
 
 
 def _assert_clean(server, sched):
-    assert sched.stats["launches"] == sched.stats["steps"]
-    assert sched.stats["padded_calls"] == 0
+    assert len(sched.step_positions) == sched.stats["steps"]
     sched.close()
     pool = server.engine_dispatch_stats()["kv_pool"]
     assert pool["leases_active"] == 0, pool
