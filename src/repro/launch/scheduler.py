@@ -25,7 +25,9 @@ dimension instead:
     row's prefill cache is copied into its slot and the per-request
     buffers released back immediately, and when any row outgrows the
     bucket the shared cache grows through the pool
-    (``VortexServer._grow_cache``) exactly like the serial path.
+    (``VortexServer._grow_cache``) exactly like the serial path.  Each
+    decode launch donates the shared leaves and hands back the updated
+    ones: the program writes each row's new K/V into them in place.
 
 Step-granular contract (asserted by tests/test_scheduler.py): one AOT
 launch per batched decode step, and per-request outputs token-identical
@@ -433,8 +435,10 @@ class ContinuousScheduler:
         Failure isolation: an exception while admitting resolves THAT
         request to a ``RequestError``; one while growing fails only the
         rows that needed the larger bucket; one in the decode launch fails
-        the rows that shared it.  Nothing propagates out of ``step()`` —
-        the loop, the shared cache, and the lease ledger stay serviceable.
+        the rows that shared it (and, if the launch consumed the donated
+        shared cache, drops it for the next admission to re-lease).
+        Nothing propagates out of ``step()`` — the loop, the shared cache,
+        and the lease ledger stay serviceable.
         """
         rec: dict = {"t0": time.perf_counter(), "phases": {}, "admits": []}
         with _Phase(rec, "sched.step"):
@@ -525,9 +529,17 @@ class ContinuousScheduler:
                     jnp.asarray(pos),
                 )
         except Exception as exc:
-            # The launch raised before the cache assignment: the shared
-            # leaves are exactly the pre-step state.  Every row that
-            # shared this launch resolves to a typed error.
+            # The launch raised before the cache assignment.  The program
+            # donates the cache, so a launch that ran consumed the shared
+            # leaves: drop them (settling their leases) and let the next
+            # admission lease afresh.  Otherwise they are the pre-step
+            # state.  Either way every row that shared this launch
+            # resolves to a typed error.
+            if any(
+                leaf.is_deleted()
+                for entry in self.cache.values() for leaf in entry.values()
+            ):
+                self.close()
             for rid in {row.rid for _, row in active}:
                 self._fail_request(rid, "decode", exc)
             return True

@@ -72,7 +72,7 @@ from repro.core.engine import DispatchStats
 from repro.core.hardware import resolve_platform
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
-from repro.models.model import abstract_cache
+from repro.models.model import abstract_cache, decode_updates_in_place
 from repro.models.params import init_params
 from repro.models.partitioning import make_rules
 from repro.models.registry import get_config, get_smoke_config
@@ -213,9 +213,12 @@ class KVBucketPool:
         key = (tuple(shape), jnp.dtype(dtype).name)
         buf = None
         with self._lock:
-            free = self._free.get(key)
-            if free and not zero:
+            free = self._free.get(key) if not zero else None
+            while free and buf is None:
                 buf = free.pop()
+                if buf.is_deleted():  # donated to a program since parked
+                    buf = None
+            if buf is not None:
                 self.lease_hits += 1
             else:
                 self.lease_allocs += 1
@@ -236,9 +239,11 @@ class KVBucketPool:
     def release(self, leaf: jax.Array, *, reuse: bool = True) -> None:
         """Return a leased buffer.  ``reuse=False`` retires it to the GC
         (zero-required leaves gain nothing from parking — their next
-        lease allocates fresh zeros anyway) but still settles the lease."""
+        lease allocates fresh zeros anyway) but still settles the lease.
+        A buffer a program consumed (donated, so deleted) settles its lease
+        and is never parked."""
         with self._lock:
-            if reuse:
+            if reuse and not leaf.is_deleted():
                 free = self._free.setdefault(
                     (tuple(leaf.shape), jnp.dtype(leaf.dtype).name), []
                 )
@@ -339,6 +344,7 @@ class VortexServer:
             "prefill_compiles": 0, "bucket_hits": 0,
             "decode_compiles": 0, "decode_bucket_hits": 0,
             "chained_prefills": 0,
+            "decode_inplace_launches": 0, "decode_restack_launches": 0,
         }
         # Lazy-chain prefill state: per-(bp, sp) alignment verdicts, the
         # unstacked per-layer params in scan order, and the head matrix.
@@ -460,27 +466,7 @@ class VortexServer:
         attention dispatches through the kv_len-masked decode workload at
         the bucket-aligned cache length, so the compiled step embeds the
         lattice-selected kv block and runs pad-free."""
-        key = (bp, kvb)
-        exe = self._decode_exec.get(key)
-        if exe is None:
-            dj = self._decode_jits.get(kvb)
-            if dj is None:
-                dj = jax.jit(
-                    make_decode_step(self.cfg, self.rules, cache_len=kvb)
-                )
-                self._decode_jits[kvb] = dj
-            with self.engine.use():
-                exe = dj.lower(
-                    self.params,
-                    abstract_cache(self.cfg, bp, kvb),
-                    jax.ShapeDtypeStruct((bp, 1), jnp.int32),
-                    jax.ShapeDtypeStruct((), jnp.int32),
-                ).compile()
-            self._decode_exec[key] = exe
-            self.stats["decode_compiles"] += 1
-        else:
-            self.stats["decode_bucket_hits"] += 1
-        return exe
+        return self._hand_out(self._decode_exec, bp, kvb, ())
 
     def _decode_exec_vec_for(self, bp: int, kvb: int) -> "jax.stages.Compiled":
         """The mixed-progress decode program for a (batch-bucket,
@@ -489,13 +475,36 @@ class VortexServer:
         advances rows sitting at DIFFERENT kv positions — the scheduler's
         batched step.  Shares the jit family (and the compile counters)
         with the scalar program; the compiled artifacts are distinct."""
+        return self._hand_out(self._decode_exec_vec, bp, kvb, (bp,))
+
+    def _hand_out(self, programs: dict, bp: int, kvb: int, pos_shape):
+        """A decode program for a launch, counted by the path its cache
+        update was compiled on: ``decode_inplace_launches`` (the stacked
+        cache is updated in place) or ``decode_restack_launches``."""
         key = (bp, kvb)
-        exe = self._decode_exec_vec.get(key)
+        if key in programs:
+            self.stats["decode_bucket_hits"] += 1
+        exe = self._decode_program(programs, bp, kvb, pos_shape)
+        inplace = decode_updates_in_place(self.cfg, self.rules, kvb)
+        self.stats[
+            "decode_inplace_launches" if inplace
+            else "decode_restack_launches"
+        ] += 1
+        return exe
+
+    def _decode_program(self, programs: dict, bp: int, kvb: int, pos_shape):
+        """Compile (once) the decode program for ``(bp, kvb)`` with ``pos``
+        of ``pos_shape`` into ``programs``.  The cache argument is donated:
+        the program's output cache takes over the input's buffers, which
+        are deleted by the call."""
+        key = (bp, kvb)
+        exe = programs.get(key)
         if exe is None:
             dj = self._decode_jits.get(kvb)
             if dj is None:
                 dj = jax.jit(
-                    make_decode_step(self.cfg, self.rules, cache_len=kvb)
+                    make_decode_step(self.cfg, self.rules, cache_len=kvb),
+                    donate_argnums=(1,),
                 )
                 self._decode_jits[kvb] = dj
             with self.engine.use():
@@ -503,12 +512,10 @@ class VortexServer:
                     self.params,
                     abstract_cache(self.cfg, bp, kvb),
                     jax.ShapeDtypeStruct((bp, 1), jnp.int32),
-                    jax.ShapeDtypeStruct((bp,), jnp.int32),
+                    jax.ShapeDtypeStruct(pos_shape, jnp.int32),
                 ).compile()
-            self._decode_exec_vec[key] = exe
+            programs[key] = exe
             self.stats["decode_compiles"] += 1
-        else:
-            self.stats["decode_bucket_hits"] += 1
         return exe
 
     # Which axis of each cache leaf is the cache-length dim (leaves carry a
@@ -537,7 +544,9 @@ class VortexServer:
 
     def release_cache(self, cache: dict) -> None:
         """Return every growable leaf to the pool — request retirement
-        (and the ``generate`` exception path) funds future leases."""
+        (and the ``generate`` exception path) funds future leases.  Leaves
+        a decode launch consumed (donated, then raised) settle their
+        leases without being parked."""
         for entry, name in self._cache_kv_leaves(cache):
             self.kv_pool.release(
                 entry[name], reuse=name in self._POOLED_STALE_OK
@@ -839,7 +848,7 @@ class VortexServer:
                     compiled += 1
             for kvb in self.decode_buckets(m_max=m_max, max_new=max_new):
                 if (bp, kvb) not in self._decode_exec:
-                    self._decode_exec_for(bp, kvb)
+                    self._decode_program(self._decode_exec, bp, kvb, ())
                     compiled += 1
             if bp >= pow2_bucket(max_batch):
                 break

@@ -23,6 +23,7 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.kernels.ref import chunked_attention, ref_attention
 from repro.models.config import LayerSpec, ModelConfig
@@ -355,6 +356,66 @@ def flash_decode_sharded(
     return fn(q, k_cache, v_cache, k_new, v_new, pos)
 
 
+def decode_seq_sharded(rules: AxisRules, cache_len: int) -> bool:
+    """True when a decode KV cache of ``cache_len`` positions shards on the
+    sequence axis (the kv heads do not divide the TP axis), so decode runs
+    :func:`flash_decode_sharded`."""
+    model_size = rules.axis_sizes.get("model", 1)
+    return (
+        rules.mesh is not None
+        and rules.rules.get("seq") is not None
+        and rules.rules.get("kv_heads_act") is None
+        and cache_len % max(model_size, 1) == 0
+        and model_size > 1
+    )
+
+
+def _write_token_rows(
+    k_leaf: jax.Array,  # (G, b, KV, S, hd): every layer's K cache, stacked
+    v_leaf: jax.Array,
+    k: jax.Array,       # (b, KV, 1, hd): this token's K
+    v: jax.Array,
+    layer: jax.Array,
+    pos: jax.Array,
+    rules: AxisRules,
+) -> tuple[jax.Array, jax.Array]:
+    """Write one token's K and V into layer ``layer`` of the stacked cache
+    leaves in place: every row at scalar ``pos``, or row r at ``pos[r]``
+    (one ``dynamic_update_slice`` per row — a scatter or a vmap over the
+    row axis compiles to whole-leaf copies).  Each result is pinned to the
+    layout the device stores the leaf in, the layout of the program's
+    donated input and its output: left free, the compiler lays the carried
+    leaves out for the row writes and copies both whole leaves in and out
+    of that layout every launch."""
+    dev = (
+        rules.mesh.devices.flat[0] if rules.mesh is not None
+        else jax.devices()[0]
+    )
+    layout = Layout.from_pjrt_layout(dev.client.get_default_layout(
+        k_leaf.dtype, k_leaf.shape, dev
+    ))
+
+    def write(leaf, new, start):
+        return with_layout_constraint(
+            jax.lax.dynamic_update_slice(leaf, new, start), layout
+        )
+
+    k = k.astype(k_leaf.dtype)
+    v = v.astype(v_leaf.dtype)
+    if not getattr(pos, "ndim", 0):
+        start = (layer, 0, 0, pos, 0)
+        return write(k_leaf, k[None], start), write(v_leaf, v[None], start)
+
+    def row(r, kv):
+        start = (layer, r, 0, pos[r], 0)
+        return (
+            write(kv[0], k[r][None, None], start),
+            write(kv[1], v[r][None, None], start),
+        )
+
+    return jax.lax.fori_loop(0, k.shape[0], row, (k_leaf, v_leaf))
+
+
 def attn_forward(
     p: dict,
     x: jax.Array,
@@ -370,8 +431,14 @@ def attn_forward(
     causal: bool = True,
     use_rope: bool = True,
     encoder_out: jax.Array | None = None,
+    layer: jax.Array | None = None,
 ) -> tuple[jax.Array, dict | None]:
-    """GQA attention with RoPE, sliding window, logit softcap, cross-attn."""
+    """GQA attention with RoPE, sliding window, logit softcap, cross-attn.
+
+    In decode, ``layer`` set means ``cache`` holds the STACKED leaves
+    (G, b, KV, S, hd) carried through the layer scan: the new token's K/V
+    are written into layer ``layer`` in place and the updated leaves are
+    returned.  Otherwise ``cache`` is this layer's own slice."""
     b, s, d = x.shape
     hd = cfg.resolved_head_dim
     H, KV = cfg.n_heads, cfg.n_kv_heads
@@ -412,18 +479,21 @@ def attn_forward(
 
     scale = hd ** -0.5
     new_cache: dict | None = None
-    if mode == "decode":
+    if mode == "decode" and layer is not None:
         assert cache is not None and pos is not None
-        S = cache["k"].shape[2]
-        model_size = rules.axis_sizes.get("model", 1)
-        seq_sharded = (
-            rules.mesh is not None
-            and rules.rules.get("seq") is not None
-            and rules.rules.get("kv_heads_act") is None
-            and S % max(model_size, 1) == 0
-            and model_size > 1
+        k_leaf, v_leaf = _write_token_rows(
+            cache["k"], cache["v"], k, v, layer, pos, rules
         )
-        if seq_sharded:
+        out = _decode_attend(
+            q,
+            jax.lax.dynamic_index_in_dim(k_leaf, layer, 0, keepdims=False),
+            jax.lax.dynamic_index_in_dim(v_leaf, layer, 0, keepdims=False),
+            pos, spec.window, cfg.attn_softcap, scale,
+        )
+        new_cache = {"k": k_leaf, "v": v_leaf}
+    elif mode == "decode":
+        assert cache is not None and pos is not None
+        if decode_seq_sharded(rules, cache["k"].shape[2]):
             out, k_cache, v_cache = flash_decode_sharded(
                 q, cache["k"], cache["v"], k, v, pos,
                 spec.window, cfg.attn_softcap, scale, rules,

@@ -23,6 +23,7 @@ from jax.sharding import PartitionSpec as P
 from repro.models.config import LayerSpec, ModelConfig
 from repro.models.layers import (
     attn_forward,
+    decode_seq_sharded,
     mamba_forward,
     mla_forward,
     mlp_forward,
@@ -33,6 +34,7 @@ from repro.models.partitioning import AxisRules, constrain
 
 __all__ = [
     "forward",
+    "decode_updates_in_place",
     "loss_fn",
     "make_cache",
     "abstract_cache",
@@ -166,6 +168,7 @@ def _apply_layer(
     encoder_out: jax.Array | None,
     causal: bool = True,
     use_rope: bool = True,
+    layer: jax.Array | None = None,
 ) -> tuple[jax.Array, dict | None, jax.Array, jax.Array]:
     aux = jnp.zeros((), jnp.float32)
     dropped = jnp.zeros((), jnp.float32)
@@ -176,6 +179,7 @@ def _apply_layer(
             mode=mode, positions=positions, cache=cache, pos=pos,
             cache_len=cache_len,
             causal=causal, use_rope=use_rope, encoder_out=encoder_out,
+            layer=layer,
         )
     elif spec.mixer == "mla":
         y, new_cache = mla_forward(
@@ -226,6 +230,25 @@ def _encode(
 
     x, _ = jax.lax.scan(body, x, enc["layers"])
     return norm(x, enc["final_norm"], cfg)
+
+
+def decode_updates_in_place(
+    cfg: ModelConfig, rules: AxisRules, cache_len: int
+) -> bool:
+    """True when decode updates the stacked cache in place: every layer is
+    self-attention with a k/v cache (no MLA, mamba state, cross-attention
+    or encoder output) and the cache is not sequence-sharded.  The stacked
+    leaves then ride the layer scan's carry and each layer writes only the
+    new token's rows into them; otherwise each layer returns its rewritten
+    slice and the scan restacks them into a new cache."""
+    return (
+        not cfg.encoder_decoder
+        and all(
+            spec.mixer == "attn" and not spec.cross_attn
+            for spec in cfg.pattern
+        )
+        and not decode_seq_sharded(rules, cache_len)
+    )
 
 
 def forward(
@@ -305,6 +328,26 @@ def forward(
     new_cache: dict[str, Any] = {}
 
     n_pos = len(cfg.pattern)
+    in_place = mode == "decode" and decode_updates_in_place(
+        cfg, rules, cache_len
+    )
+
+    def carried_body(carry, xs):
+        # The stacked leaves ride the carry: layer g writes its new K/V
+        # rows into them in place and reads its slice back for attention.
+        x, aux, dropped, kv = carry
+        p_slices, g = xs
+        kv = list(kv)
+        for i in range(n_pos):
+            x, kv[i], aux_i, dropped_i = _apply_layer(
+                cfg, cfg.pattern[i], rules, p_slices[i], x,
+                mode=mode, positions=None, cache=kv[i], pos=pos,
+                cache_len=cache_len, encoder_out=None,
+                use_rope=cfg.use_rope, layer=g,
+            )
+            aux = aux + aux_i
+            dropped = dropped + dropped_i
+        return (x, aux, dropped, tuple(kv)), None
 
     def group_body(carry, xs):
         x, aux, dropped = carry
@@ -334,14 +377,22 @@ def forward(
         tuple(cache[f"pos{i}"] for i in range(n_pos))
         if mode == "decode" else None
     )
-    (x, aux_total, dropped_total), ys = jax.lax.scan(
-        group_body, (x, aux_total, dropped_total), (p_stacked, c_stacked)
-    )
-    if ys is not None:
+    if in_place:
+        (x, aux_total, dropped_total, kv), _ = jax.lax.scan(
+            carried_body, (x, aux_total, dropped_total, c_stacked),
+            (p_stacked, jnp.arange(cfg.n_groups, dtype=jnp.int32)),
+        )
         for i in range(n_pos):
-            new_cache[f"pos{i}"] = ys[i]
-        if cfg.encoder_decoder:
-            new_cache["encoder_out"] = encoder_out
+            new_cache[f"pos{i}"] = kv[i]
+    else:
+        (x, aux_total, dropped_total), ys = jax.lax.scan(
+            group_body, (x, aux_total, dropped_total), (p_stacked, c_stacked)
+        )
+        if ys is not None:
+            for i in range(n_pos):
+                new_cache[f"pos{i}"] = ys[i]
+            if cfg.encoder_decoder:
+                new_cache["encoder_out"] = encoder_out
 
     x = norm(x, params["final_norm"], cfg)
     head = (

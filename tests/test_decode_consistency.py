@@ -61,31 +61,51 @@ def _decode_inputs(cfg, key, b=2, prefill_len=32, extra=3):
     return tokens, total, kw
 
 
-def _run_decode_vs_full(cfg, mesh, gate):
+def _run_decode_vs_full(cfg, mesh, gate, per_row=False):
     """Decode the last tokens one by one against the train-mode logits,
-    calling ``gate(full_logits_at_pos, decode_logits)`` per step."""
+    calling ``gate(full_logits_at_pos, decode_logits)`` per step.
+
+    ``per_row``: each step is one launch with a (b,) position vector, row
+    r two positions behind row r-1 (it rewrites the last prompt rows it
+    re-feeds), as the scheduler's mixed-progress step serves rows."""
     rules = make_rules(mesh, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads)
     key = jax.random.PRNGKey(0)
     params = init_params(cfg, key)
-    prefill_len, extra = 32, 3
-    tokens, total, kw = _decode_inputs(cfg, key, 2, prefill_len, extra)
+    b, prefill_len, extra = 2, 32, 3
+    tokens, total, kw = _decode_inputs(cfg, key, b, prefill_len, extra)
 
     full, _, _ = M.forward(cfg, rules, params, tokens, mode="train", **kw)
     _, cache, _ = M.forward(
         cfg, rules, params, tokens[:, :prefill_len], mode="prefill",
         cache_len=total, **kw,
     )
+    rows = jnp.arange(b)
     for i in range(extra):
-        pos = prefill_len + i
+        if per_row:
+            pos = prefill_len + i - 2 * rows
+            tok = tokens[rows, pos][:, None]
+        else:
+            pos = jnp.asarray(prefill_len + i, jnp.int32)
+            tok = tokens[:, prefill_len + i: prefill_len + i + 1]
         dec, cache, _ = M.forward(
-            cfg, rules, params, tokens[:, pos: pos + 1], mode="decode",
-            cache=cache, pos=jnp.asarray(pos, jnp.int32), cache_len=total,
+            cfg, rules, params, tok, mode="decode",
+            cache=cache, pos=pos.astype(jnp.int32), cache_len=total,
         )
-        gate(full[:, pos], dec[:, 0], pos)
+        want = full[rows, pos] if per_row else full[:, int(pos)]
+        gate(want, dec[:, 0], np.asarray(pos).tolist())
 
 
-@pytest.mark.parametrize("arch", ARCHS)
-def test_decode_matches_full_forward(arch, mesh):
+# Mixed per-row positions through the model's own decode: the in-place
+# path with sliding windows and softcaps (gemma2), and latent attention's
+# restack path (deepseek-v2).  The scheduler covers paper-gpt2.
+PER_ROW_ARCHS = ("gemma2-9b", "deepseek-v2-236b")
+DECODE_CASES = [pytest.param(a, False, id=a) for a in ARCHS] + [
+    pytest.param(a, True, id=f"{a}-per_row") for a in PER_ROW_ARCHS
+]
+
+
+@pytest.mark.parametrize("arch,per_row", DECODE_CASES)
+def test_decode_matches_full_forward(arch, per_row, mesh):
     """float32 end-to-end: the comparison is deterministic, so the gate is
     strict — a real decode/cache bug moves logits by orders of magnitude
     more than f32 reduction-order noise."""
@@ -99,7 +119,7 @@ def test_decode_matches_full_forward(arch, mesh):
         err = np.abs(a - b_) / (np.max(np.abs(a)) + 1e-9)
         assert float(np.max(err)) < 2e-3, (arch, pos, float(np.max(err)))
 
-    _run_decode_vs_full(cfg, mesh, gate)
+    _run_decode_vs_full(cfg, mesh, gate, per_row)
 
 
 @pytest.mark.contention

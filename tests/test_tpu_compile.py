@@ -13,9 +13,11 @@ this file.  Where it cannot be described, the compile tests skip.
 """
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.experimental.pallas import tpu as pltpu
 from jax.sharding import SingleDeviceSharding
@@ -162,6 +164,95 @@ def test_grouped_gemm_compiles_at_granite_widths(engine, one_chip, sig):
         ((E, k, n), jnp.bfloat16), ((E,), jnp.int32),
     )
     assert "tpu_custom_call" in hlo
+
+
+class _KernelSession:
+    """Stands in for the serving session while a decode program is traced:
+    decode attention becomes the Pallas kernel compiled for the chip at
+    the lattice's selection, as ``DecodeAttentionWorkload`` builds it."""
+
+    def __init__(self, engine):
+        self.engine = engine
+
+    def dispatch(self, kind, q, k, v, kv_len, *, window=None, softcap=None):
+        assert kind == "decode_attention"
+        kern = self.engine.kernel_for(
+            DecodeAttentionWorkload(seq=None, head_dim=q.shape[-1])
+        )
+        sel = kern.select(k.shape[-2])
+        return flash_attention(
+            q, k, v, kv_len, q_offset=kv_len - 1, block_q=1,
+            block_k=sel.strategy.l1[2], causal=False, window=window,
+            softcap=softcap, interpret=False,
+            vmem_limit_bytes=kern.vmem_limit_bytes,
+        )
+
+
+@pytest.mark.parametrize("per_row", [False, True], ids=["scalar", "per_row"])
+def test_decode_program_updates_cache_in_place(
+    engine, topo, per_row, monkeypatch
+):
+    """The whole donated decode program at full width, with the Pallas
+    decode kernel, for one described chip: every cache leaf is aliased to
+    the output, and no whole stacked leaf is copied (into another layout
+    or otherwise), broadcast or transposed."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from repro.models.model import abstract_cache
+    from repro.models.params import init_params
+    from repro.models.partitioning import make_rules
+    from repro.train.step import make_decode_step
+    from repro.vortex import session
+
+    monkeypatch.setattr(
+        session, "installed_engine", lambda: _KernelSession(engine)
+    )
+    mesh = Mesh(
+        np.asarray(topo.devices[:1]).reshape(1, 1), ("data", "model")
+    )
+    on_chip = NamedSharding(mesh, PartitionSpec())
+    rules = make_rules(mesh, n_heads=GPT2.n_heads, n_kv_heads=GPT2.n_kv_heads)
+    kvb = engine.kernel_for(
+        DecodeAttentionWorkload(seq=None, head_dim=HD)
+    ).select(1024).bucket[2]
+    b = 8
+
+    def shapes(tree):
+        return jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=on_chip),
+            tree,
+        )
+
+    params = jax.eval_shape(lambda: init_params(GPT2, jax.random.PRNGKey(0)))
+    cache = abstract_cache(GPT2, b, kvb)
+    step = jax.jit(
+        make_decode_step(GPT2, rules, cache_len=kvb), donate_argnums=(1,)
+    )
+    hlo = step.lower(
+        shapes(params), shapes(cache),
+        jax.ShapeDtypeStruct((b, 1), jnp.int32, sharding=on_chip),
+        jax.ShapeDtypeStruct((b,) if per_row else (), jnp.int32,
+                             sharding=on_chip),
+    ).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    header = hlo.splitlines()[0]
+    n_params = len(jax.tree.leaves(params))
+    n_cache = len(jax.tree.leaves(cache))
+    aliased = {
+        int(p) for p in re.findall(r"\}: \((\d+), \{\}, may-alias\)", header)
+    }
+    assert aliased == set(range(n_params, n_params + n_cache)), header
+    leaf = re.escape("[" + ",".join(map(str, cache["pos0"]["k"].shape)) + "]")
+    whole = [
+        (name, op)
+        for name, op in re.findall(rf"%(\S+) = \w+{leaf}\S* ([\w-]+)\(", hlo)
+        if op in ("copy", "broadcast", "transpose", "custom-call")
+        or (op == "fusion"
+            and any(w in name for w in ("copy", "broadcast", "transpose")))
+    ]
+    assert not whole, whole
+    # The new token's rows are written, not selected into a whole slice.
+    assert "broadcast_select_fusion" not in hlo
 
 
 def _pallas_calls(jaxpr):
