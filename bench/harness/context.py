@@ -3,12 +3,19 @@ scheduler's per-launch positions, the reduced trace, sizes and peaks.
 
 Readers return a number, or None where the run holds nothing to read (no
 trace, no admission in the traced span, a kernel that is not on the path).
+
+The arithmetic from shapes holds for every decoder ``system.serves``:
+attention projections at ``heads`` query and ``kv_heads`` key/value heads,
+and a gated MLP (``swiglu``, ``geglu``) counted with its third matrix.
+The attention kernels' own counts (``bench/kernels/``) already read K and
+V bytes by ``kv_heads``.
 """
 from __future__ import annotations
 
 import dataclasses
 
 from bench.harness import spec
+from bench.harness.weights import GATED
 
 
 @dataclasses.dataclass
@@ -55,7 +62,7 @@ class Context:
         z = self.sizes
         q = z["heads"] * z["head_dim"]
         kv = z["kv_heads"] * z["head_dim"]
-        mlp = 2 * z["d_model"] * z["d_ff"]
+        mlp = (3 if z["act"] in GATED else 2) * z["d_model"] * z["d_ff"]
         return z["layers"] * (2 * z["d_model"] * q + 2 * z["d_model"] * kv + mlp)
 
     def head_params(self) -> int:

@@ -1,22 +1,29 @@
 """The system under test: ``VortexServer(prefill="aot")`` under
 ``ContinuousScheduler``, built from a configuration and warmed for a mix.
+
+Set-up checks, before anything compiles, that the program's ModelConfig is
+a decoder this harness serves (``serves``), that it agrees with every key
+of the configuration's ``as_run`` block, and that the weight tree has the
+form of the program's own parameter schema.
 """
 from __future__ import annotations
 
 from unittest import mock
 
+import jax
 import numpy as np
 
 from bench.harness import traffic
-
-# Structural sizes the program's ModelConfig must agree on with the
-# configuration's ``as_run`` block (the program hard-codes its norm eps).
-_CHECKED = ("layers", "d_model", "heads", "kv_heads", "head_dim", "d_ff",
-            "vocab", "vocab_padded", "norm", "act", "rope_theta", "dtype")
+from bench.harness import weights as harness_weights
 
 
 def program_sizes(cfg) -> dict:
-    """The ``as_run`` block of a program ModelConfig (smoke rehearsals)."""
+    """The ``as_run`` block of a program ModelConfig (smoke rehearsals).
+
+    Its keys are the ones the harness maps to program attributes of
+    another name or form (the program hard-codes its norm eps); any other
+    ``as_run`` key names a ModelConfig attribute itself.
+    """
     return {
         "layers": cfg.n_layers, "d_model": cfg.d_model,
         "heads": cfg.n_heads, "kv_heads": cfg.n_kv_heads,
@@ -29,28 +36,80 @@ def program_sizes(cfg) -> dict:
     }
 
 
+def serves(cfg) -> bool:
+    """Whether ``cfg`` is a decoder this harness makes, feeds and counts:
+    RoPE, every layer full causal self-attention with a dense MLP, no
+    image prefix and no encoder."""
+    return (cfg.use_rope and not cfg.vision_prefix
+            and not cfg.encoder_decoder
+            and all(s.mixer == "attn" and s.mlp == "dense" and not s.window
+                    and not s.cross_attn for s in cfg.pattern))
+
+
 def program_config(arch: str, sizes: dict | None, *, smoke: bool = False):
     """The program's ModelConfig for ``arch``, checked against ``sizes``."""
     from repro.models.registry import get_config, get_smoke_config
 
     cfg = get_smoke_config(arch) if smoke else get_config(arch)
-    if sizes is not None:
-        have = program_sizes(cfg)
-        bad = {k: (sizes[k], have[k]) for k in _CHECKED if sizes[k] != have[k]}
-        if bad or not cfg.use_rope or any(
-            s.mixer != "attn" or s.mlp != "dense" or s.window or s.cross_attn
-            for s in cfg.pattern
-        ):
-            raise SystemExit(
-                f"program config {arch!r} is not the configuration as run: "
-                f"(file, program) = {bad}"
-            )
+    if not serves(cfg):
+        raise SystemExit(
+            f"program config {arch!r} is not a uniform attention-plus-dense "
+            "decoder with RoPE, no window, no image prefix and no encoder"
+        )
+    if sizes is None:
+        return cfg
+    have = program_sizes(cfg)
+    missing = sorted(set(have) - set(sizes))
+    unknown, bad = [], {}
+    for k, v in sizes.items():
+        if k in have:
+            mine = have[k]
+        elif hasattr(cfg, k) and not callable(getattr(cfg, k)):
+            mine = getattr(cfg, k)
+        else:
+            unknown.append(k)
+            continue
+        if v != mine:
+            bad[k] = (v, mine)
+    if missing or unknown or bad:
+        raise SystemExit(
+            f"program config {arch!r} is not the configuration as run: "
+            f"as_run keys the program config does not have {unknown}; "
+            f"as_run lacks {missing}; (file, program) = {bad}"
+        )
+    check_tree(cfg, harness_weights.abstract(sizes))
     return cfg
+
+
+def _form(tree) -> dict:
+    """{path: (shape, dtype)} of every leaf of a tree of dicts."""
+    return {
+        "/".join(str(k.key) for k in path): (tuple(x.shape), str(x.dtype))
+        for path, x in jax.tree_util.tree_flatten_with_path(tree)[0]
+    }
+
+
+def check_tree(cfg, tree) -> None:
+    """Exit unless ``tree`` has the form of the program's parameters for
+    ``cfg``: the same paths, shapes and dtypes.  Values are not compared."""
+    from repro.models.params import abstract_params
+
+    want, got = _form(abstract_params(cfg)), _form(tree)
+    missing = sorted(set(want) - set(got))
+    extra = sorted(set(got) - set(want))
+    differ = {p: (got[p], want[p]) for p in sorted(set(got) & set(want))
+              if got[p] != want[p]}
+    if missing or extra or differ:
+        raise SystemExit(
+            f"the weight tree is not the program's layout for {cfg.name!r}: "
+            f"missing {missing}; extra {extra}; (harness, program) = {differ}"
+        )
 
 
 def build(cfg, weights: dict, mix: dict):
     """Server and scheduler over ``weights`` (the server's own initialiser
     is bypassed, so no second copy of the weights is ever made)."""
+    check_tree(cfg, weights)
     import repro.launch.serve as serve
     from repro.launch.mesh import make_host_mesh
     from repro.launch.scheduler import ContinuousScheduler

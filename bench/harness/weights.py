@@ -1,10 +1,17 @@
 """Weights and random streams drawn from a run's ``--seed``.
 
 The weights are made on the device in one jitted call, in the dtype they
-are served in, in the parameter layout of the program's GPT-2-style
-decoder (see ``bench/reference/dense_decoder.py``).  The plain reference
-reads the same tree, so both sides see one set of numbers that neither the
-program nor its initialiser made.
+are served in, in the parameter layout of the program's uniform
+attention-plus-dense decoders (see ``bench/reference/dense_decoder.py``):
+one stacked block, grouped-query attention, and an MLP that is gated
+(``w_gate`` beside ``w_in``) when ``act`` is ``swiglu`` or ``geglu``.
+``system.build`` checks the tree's form against the program's own schema;
+its values stay the harness's, so the plain reference reads one set of
+numbers that neither the program nor its initialiser made.
+
+Leaf i of the flattened tree (keys in sorted order) is drawn from
+``fold_in(key, i)``: a decoder without the gated leaf has the tree, and
+so the numbers, it had before the gated leaf existed.
 """
 from __future__ import annotations
 
@@ -21,11 +28,17 @@ def rng(seed: int, stream: int) -> np.random.Generator:
     return np.random.default_rng(np.random.SeedSequence([abs(seed), stream]))
 
 
+GATED = ("swiglu", "geglu")
+
+
 def shapes(sizes: dict) -> dict:
     """{path: (shape, fan_in)} of every leaf; fan_in None marks a norm."""
     L, d, f = sizes["layers"], sizes["d_model"], sizes["d_ff"]
     q = sizes["heads"] * sizes["head_dim"]
     kv = sizes["kv_heads"] * sizes["head_dim"]
+    mlp = {"w_in": ((L, d, f), d), "w_out": ((L, f, d), f)}
+    if sizes["act"] in GATED:
+        mlp["w_gate"] = ((L, d, f), d)
     return {
         "embed": ((sizes["vocab_padded"], d), d),
         "final_norm": ((d,), None),
@@ -36,9 +49,16 @@ def shapes(sizes: dict) -> dict:
                 "wq": ((L, d, q), d), "wk": ((L, d, kv), d),
                 "wv": ((L, d, kv), d), "wo": ((L, q, d), q),
             },
-            "mlp": {"w_in": ((L, d, f), d), "w_out": ((L, f, d), f)},
+            "mlp": mlp,
         },
     }
+
+
+def abstract(sizes: dict) -> dict:
+    """The form of ``make``'s tree, as ``jax.ShapeDtypeStruct`` leaves."""
+    dt = jnp.dtype(sizes["dtype"])
+    return jax.tree.map(lambda leaf: jax.ShapeDtypeStruct(leaf[0], dt),
+                        shapes(sizes), is_leaf=_is_leaf)
 
 
 def _is_leaf(x) -> bool:

@@ -10,13 +10,16 @@ layout that ``bench/harness/weights.py`` makes them in:
 
     embed (vocab_padded, d)          final_norm (d,)
     pos0/norm_mixer (L, d)           pos0/norm_mlp (L, d)
-    pos0/attn/{wq, wk, wv (L, d, H*hd), wo (L, H*hd, d)}
+    pos0/attn/{wq (L, d, H*hd), wk, wv (L, d, KV*hd), wo (L, H*hd, d)}
     pos0/mlp/{w_in (L, d, f), w_out (L, f, d)}
+    pos0/mlp/w_gate (L, d, f)        gated MLPs (swiglu, geglu) only
 
-and computes one sequence at a time, layer by layer, in float32 at
-``precision=HIGHEST`` (a TPU otherwise multiplies float32 in bfloat16).
-Sequences are padded to a multiple of ``PAD`` rows at the end: causal
-attention keeps the real rows exact, and few distinct shapes compile.
+This module computes that layout with LayerNorm, KV = H and no gate, and
+refuses any other form.  It computes one sequence at a time, layer by
+layer, in float32 at ``precision=HIGHEST`` (a TPU otherwise multiplies
+float32 in bfloat16).  Sequences are padded to a multiple of ``PAD`` rows
+at the end: causal attention keeps the real rows exact, and few distinct
+shapes compile.
 
 ``fp8=True`` is the control: every weight GEMM takes its operands through
 float8 e4m3 (per-output-channel weight scales, per-row activation scales),

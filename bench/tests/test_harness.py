@@ -12,6 +12,7 @@ import subprocess
 import sys
 from pathlib import Path
 
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -78,13 +79,17 @@ def _broken(kind: str):
         exe = real(self, bp, kvb)
 
         def call(params, cache, tok, pos):
+            if kind == "state_unchanged":
+                # The program consumes the cache it is given (donated), so
+                # the cache handed back is a copy taken before the call.
+                before = jax.tree.map(jnp.copy, cache)
             logits, new = exe(params, cache, tok, pos)
             if kind == "token_altered":
                 top = (jnp.argmax(logits, -1) + 1) % self.cfg.vocab
                 rows = jnp.arange(logits.shape[0])
                 logits = logits.at[rows, top].set(1e4)
             elif kind == "state_unchanged":
-                new = cache
+                new = before
             elif kind == "half_batch":
                 half = logits.shape[0] // 2
                 logits = logits.at[half:].set(logits[:1])
