@@ -1,7 +1,12 @@
 """phi4-mini-3.8b [dense]: RoPE + SwiGLU + GQA.
 
-32L d_model=3072 24H (GQA kv=8) d_ff=8192 vocab=200064.
-[arXiv:2412.08905; hf]
+32L d_model=3072 24H (GQA kv=8, head_dim 128) d_ff=8192 vocab=200064,
+tied embeddings.  Partial rotary: ``partial_rotary_factor`` 0.75, so the
+first 96 of each head's 128 dims rotate (split-half) and the last 32 pass
+through.  Departure: plain RoPE at theta 10000 over those dims, in place
+of the published LongRoPE ``rope_scaling`` (its short/long factor lists
+are not in this repository).
+[arXiv:2503.01743; https://huggingface.co/microsoft/Phi-4-mini-instruct]
 """
 from repro.models.config import LayerSpec, ModelConfig
 
@@ -17,6 +22,7 @@ CONFIG = ModelConfig(
     pattern=(LayerSpec(mixer="attn", mlp="dense"),),
     norm="rmsnorm",
     act="swiglu",
+    partial_rotary_factor=0.75,
     tie_embeddings=True,
     sub_quadratic=False,
 )
@@ -33,5 +39,6 @@ SMOKE = ModelConfig(
     pattern=(LayerSpec(mixer="attn", mlp="dense"),),
     norm="rmsnorm",
     act="swiglu",
+    partial_rotary_factor=0.75,  # hd 8: the first 6 dims rotate
     scan_chunk=16,
 )

@@ -23,11 +23,13 @@ dimension instead:
   * the KV state is ONE shared set of kv-bucket buffers LEASED from the
     server's :class:`~repro.launch.serve.KVBucketPool` — each admitted
     row's prefill cache is copied into its slot and the per-request
-    buffers released back immediately, and when any row outgrows the
-    bucket the shared cache grows through the pool
-    (``VortexServer._grow_cache``) exactly like the serial path.  Each
-    decode launch donates the shared leaves and hands back the updated
-    ones: the program writes each row's new K/V into them in place.
+    buffers retired at once, and when any row outgrows the bucket the
+    shared cache grows through the pool (``VortexServer._grow_cache``),
+    retiring the outgrown leaves.  Neither is parked: the scheduler never
+    leases a b=1 prefill cache's shape, and its shared cache only grows,
+    so parked copies would only hold device memory.  Each decode launch
+    donates the shared leaves and hands back the updated ones: the
+    program writes each row's new K/V into them in place.
 
 Step-granular contract (asserted by tests/test_scheduler.py): one AOT
 launch per batched decode step, and per-request outputs token-identical
@@ -153,9 +155,10 @@ class ContinuousScheduler:
     ``t1`` (``perf_counter`` at ``step()`` entry and return), ``phases``
     (seconds per span name, summed over the step), ``blocked_s`` (seconds
     in ``sched.first_token`` and ``sched.readback``, the host waiting on
-    the device) and ``admits`` (one dict per admission of the step:
-    ``rid``, ``prompt`` length, prefill ``bucket``, ``admit_s`` and
-    ``queued_s``, admission start minus ``submit()``).  A step that ends
+    the device), ``admits`` (one dict per admission of the step: ``rid``,
+    ``prompt`` length, prefill ``bucket``, ``admit_s`` and ``queued_s``,
+    admission start minus ``submit()``) and ``kv_parked_bytes`` (the
+    pool's ``parked_bytes`` as the step returns).  A step that ends
     without a launch (idle, or every row failed) records nothing, its
     admissions included; an admission that fails adds its time to
     ``phases`` and no ``admits`` entry.
@@ -275,7 +278,9 @@ class ContinuousScheduler:
     def _grow(self, new_kvb: int, rec: dict) -> None:
         assert self.cache is not None
         with _Phase(rec, "sched.grow", kvb=new_kvb):
-            self.cache = self.server._grow_cache(self.cache, new_kvb)
+            self.cache = self.server._grow_cache(
+                self.cache, new_kvb, reuse=False
+            )
         self.kvb = new_kvb
 
     def close(self) -> None:
@@ -400,7 +405,7 @@ class ContinuousScheduler:
                         if req.stop is not None and tok == req.stop:
                             self.rows[slot].remaining = 0
             finally:
-                srv.release_cache(rcache)
+                srv.release_cache(rcache, reuse=False)
         rec["admits"].append({
             "rid": rid, "prompt": s, "bucket": sp,
             "admit_s": admit.seconds, "queued_s": admit.t - submitted,
@@ -445,6 +450,7 @@ class ContinuousScheduler:
             worked = self._step(rec)
         rec["t1"] = time.perf_counter()
         rec["blocked_s"] = sum(rec["phases"].get(n, 0.0) for n in _BLOCKING)
+        rec["kv_parked_bytes"] = self.server.kv_pool.parked_bytes
         return worked
 
     def _step(self, rec: dict) -> bool:

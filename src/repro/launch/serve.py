@@ -186,8 +186,11 @@ class KVBucketPool:
     (``leases_active``; high-water mark ``leases_peak``) whether it came
     from the free list or a fresh allocation — a non-zero ``leases_active``
     at idle is a leak, asserted by the scheduler tests and surfaced via
-    ``VortexServer.engine_dispatch_stats()["kv_pool"]``.  Thread-safe: the
-    admission queue leases/releases from submitter and scheduler threads.
+    ``VortexServer.engine_dispatch_stats()["kv_pool"]``.  ``parked_bytes``
+    is the size of the buffers parked now, kept up to date as they are
+    parked, leased and dropped; ``retired`` counts the releases that parked
+    nothing.  Thread-safe: the admission queue leases/releases from
+    submitter and scheduler threads.
     """
 
     # Parked buffers per (shape, dtype) key; beyond this the oldest are
@@ -203,6 +206,8 @@ class KVBucketPool:
         self.lease_hits = 0
         self.lease_allocs = 0
         self.released = 0
+        self.retired = 0
+        self.parked_bytes = 0
 
     def lease(self, shape, dtype, *, zero: bool = False) -> jax.Array:
         """One bucket-shaped buffer: a parked one when available (stale
@@ -216,6 +221,7 @@ class KVBucketPool:
             free = self._free.get(key) if not zero else None
             while free and buf is None:
                 buf = free.pop()
+                self.parked_bytes -= buf.nbytes
                 if buf.is_deleted():  # donated to a program since parked
                     buf = None
             if buf is not None:
@@ -248,8 +254,11 @@ class KVBucketPool:
                     (tuple(leaf.shape), jnp.dtype(leaf.dtype).name), []
                 )
                 free.append(leaf)
+                self.parked_bytes += leaf.nbytes
                 if len(free) > self._MAX_PARKED:
-                    del free[0]
+                    self.parked_bytes -= free.pop(0).nbytes
+            else:
+                self.retired += 1
             self.leases_active -= 1
             self.released += 1
 
@@ -261,6 +270,8 @@ class KVBucketPool:
                 "lease_hits": self.lease_hits,
                 "lease_allocs": self.lease_allocs,
                 "released": self.released,
+                "retired": self.retired,
+                "parked_bytes": self.parked_bytes,
             }
 
 
@@ -542,17 +553,21 @@ class VortexServer:
         pool leases (they entered circulation outside ``lease``)."""
         self.kv_pool.adopt(sum(1 for _ in self._cache_kv_leaves(cache)))
 
-    def release_cache(self, cache: dict) -> None:
+    def release_cache(self, cache: dict, *, reuse: bool = True) -> None:
         """Return every growable leaf to the pool — request retirement
         (and the ``generate`` exception path) funds future leases.  Leaves
         a decode launch consumed (donated, then raised) settle their
-        leases without being parked."""
+        leases without being parked.  ``reuse=False`` retires every leaf
+        instead: a caller that never leases these shapes again parks
+        nothing."""
         for entry, name in self._cache_kv_leaves(cache):
             self.kv_pool.release(
-                entry[name], reuse=name in self._POOLED_STALE_OK
+                entry[name], reuse=reuse and name in self._POOLED_STALE_OK
             )
 
-    def _grow_cache(self, cache: dict, new_len: int) -> dict:
+    def _grow_cache(
+        self, cache: dict, new_len: int, *, reuse: bool = True
+    ) -> dict:
         """Copy the cache into ``new_len``-long bucket buffers: ONE
         O(true-size) ``dynamic_update_slice`` per growing leaf, only at
         bucket transitions — never per token.  Buffers are LEASED from the
@@ -567,7 +582,9 @@ class VortexServer:
         releases the partial new set and re-raises with ``cache``
         untouched — the caller's settling ``finally`` then releases every
         ORIGINAL lease exactly once, never double-releasing a leaf this
-        method already returned.
+        method already returned.  ``reuse=False`` retires the outgrown
+        leaves instead of parking them (a cache that only grows never
+        leases their shapes again).
         """
         st = self.decode_stats
         pool = self.kv_pool
@@ -602,7 +619,7 @@ class VortexServer:
                 pool.release(buf, reuse=stale_ok)
             raise
         for leaf, stale_ok in old_leaves:
-            pool.release(leaf, reuse=stale_ok)
+            pool.release(leaf, reuse=reuse and stale_ok)
         st.stage_copies += len(old_leaves)
         return out_cache
 

@@ -72,6 +72,7 @@ class ModelConfig:
     act: Literal["swiglu", "geglu", "gelu"] = "swiglu"
     use_rope: bool = True            # False -> sinusoidal absolute positions
     rope_theta: float = 10000.0
+    partial_rotary_factor: float = 1.0  # share of each head RoPE rotates
     attn_softcap: float | None = None
     logit_softcap: float | None = None
     tie_embeddings: bool = True
@@ -88,10 +89,26 @@ class ModelConfig:
     def __post_init__(self):
         assert self.n_layers % len(self.pattern) == 0, (
             self.name, self.n_layers, len(self.pattern))
+        rot = self.rotary_dim
+        if self.partial_rotary_factor != 1.0 and not (
+            0 < rot <= self.resolved_head_dim and rot % 2 == 0
+        ):
+            raise ValueError(
+                f"{self.name}: partial_rotary_factor "
+                f"{self.partial_rotary_factor} rotates {rot} of "
+                f"{self.resolved_head_dim} head dims; it must rotate an "
+                "even number, at least 2"
+            )
 
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def rotary_dim(self) -> int:
+        """Leading dims of each head that RoPE rotates (split-half, as
+        HF Phi3's ``rotate_half``); the rest pass through unrotated."""
+        return int(self.resolved_head_dim * self.partial_rotary_factor)
 
     @property
     def vocab_padded(self) -> int:

@@ -132,7 +132,8 @@ def norm(x: jax.Array, w: jax.Array, cfg: ModelConfig) -> jax.Array:
 def rope_tables(
     positions: jax.Array, dim: int, theta: float
 ) -> tuple[jax.Array, jax.Array]:
-    """(..., dim/2) cos/sin tables for integer positions."""
+    """(..., dim/2) cos/sin tables for integer positions, rotating ``dim``
+    dims (``ModelConfig.rotary_dim``): frequencies ``theta^(-2i/dim)``."""
     half = dim // 2
     freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None] * freq
@@ -143,7 +144,13 @@ def apply_rope(
     x: jax.Array, cos: jax.Array, sin: jax.Array
 ) -> jax.Array:
     """Rotate pairs (split-half convention). x: (..., seq, dim);
-    cos/sin: (seq, dim/2) broadcastable."""
+    cos/sin: (seq, rot/2) broadcastable.  The first ``rot`` dims rotate
+    and the rest pass through unchanged (partial rotary)."""
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1
+        )
     half = x.shape[-1] // 2
     x1, x2 = x[..., :half], x[..., half:]
     xf1, xf2 = x1.astype(jnp.float32), x2.astype(jnp.float32)
@@ -461,19 +468,21 @@ def attn_forward(
         if mode == "decode":
             assert pos is not None
             if getattr(pos, "ndim", 0):
-                # Per-row positions: (b,) -> tables (b, 1, hd/2), lifted to
-                # (b, 1, 1, hd/2) so every row rotates at ITS OWN position.
-                cos, sin = rope_tables(pos[:, None], hd, cfg.rope_theta)
+                # Per-row positions: (b,) -> tables (b, 1, rot/2), lifted to
+                # (b, 1, 1, rot/2) so every row rotates at ITS OWN position.
+                cos, sin = rope_tables(
+                    pos[:, None], cfg.rotary_dim, cfg.rope_theta
+                )
                 cos, sin = cos[:, None], sin[:, None]
             else:
                 cos, sin = rope_tables(
-                    pos[None], hd, cfg.rope_theta
-                )  # (1, hd/2)
+                    pos[None], cfg.rotary_dim, cfg.rope_theta
+                )  # (1, rot/2)
                 cos, sin = cos[None, None], sin[None, None]
         else:
             assert positions is not None
-            cos, sin = rope_tables(positions, hd, cfg.rope_theta)
-            cos, sin = cos[None, None], sin[None, None]  # (1,1,s,hd/2)
+            cos, sin = rope_tables(positions, cfg.rotary_dim, cfg.rope_theta)
+            cos, sin = cos[None, None], sin[None, None]  # (1,1,s,rot/2)
         q = apply_rope(q, cos, sin)
         k = apply_rope(k, cos, sin)
 
@@ -914,7 +923,6 @@ def attn_forward_lazy(
     """
     from repro.core.engine import LazyBucket
 
-    hd = cfg.resolved_head_dim
     H, KV = cfg.n_heads, cfg.n_kv_heads
 
     q = lazy_matmul(engine, x, p["wq"], lazy=lazy)
@@ -929,8 +937,8 @@ def attn_forward_lazy(
     q, k, v = split(q, H), split(k, KV), split(v, KV)
 
     if cfg.use_rope:
-        cos, sin = rope_tables(positions, hd, cfg.rope_theta)
-        cos, sin = cos[None, None], sin[None, None]  # (1, 1, s, hd/2)
+        cos, sin = rope_tables(positions, cfg.rotary_dim, cfg.rope_theta)
+        cos, sin = cos[None, None], sin[None, None]  # (1, 1, s, rot/2)
 
         def rope(t):
             return apply_rope(t, cos, sin)

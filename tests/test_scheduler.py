@@ -387,3 +387,66 @@ def test_threaded_submitters_stress(server):
     for i, ser in enumerate(serial):
         assert np.array_equal(results[rids[i]], ser), i
     _assert_clean(server, sched)
+
+
+def test_scheduler_parks_no_dead_cache():
+    """Admissions across every prefill bucket: the scheduler retires each
+    b=1 prefill cache after its slot copy, and the shared cache's outgrown
+    leaves as it grows, so nothing is parked — every release it makes
+    retires, each step records 0 parked bytes, and the leases settle at
+    the shared cache's own.  ``generate()``'s growth still parks."""
+    server = VortexServer(get_smoke_config("paper-gpt2-124m"),
+                          make_host_mesh(), max_cache=MAX_CACHE)
+    pool = server.kv_pool
+    sched = ContinuousScheduler(server, batch_rows=4)
+    rng = np.random.default_rng(9)
+    max_new = 4
+    buckets = server.prefill_seq_buckets(MAX_CACHE)
+    assert len(buckets) >= 2
+    lengths = [min(b, MAX_CACHE - max_new + 1) for b in buckets]
+    assert [server.prefill_seq_bucket(s) for s in lengths] == buckets
+    reqs = [Request(tokens=rng.integers(0, 512, (1, s)).astype(np.int32),
+                    max_new=max_new) for s in lengths]
+    before = pool.stats()
+    copies = server.decode_stats.stage_copies
+    for r in reqs:  # one at a time: the shared cache grows between them
+        sched.submit(r)
+        sched.drain()
+    after = pool.stats()
+    shared = sum(1 for _ in server._cache_kv_leaves(sched.cache))
+    grown = server.decode_stats.stage_copies - copies
+    assert grown > 0, "the shared cache never grew — test inert"
+    assert after["parked_bytes"] == 0
+    assert after["leases_active"] == shared
+    released = after["released"] - before["released"]
+    assert after["retired"] - before["retired"] == released
+    assert released == shared * len(reqs) + grown
+    assert all(rec["kv_parked_bytes"] == 0 for rec in sched.step_positions)
+    _assert_clean(server, sched)
+
+    parked = pool.stats()["parked_bytes"]  # close() parks the shared cache
+    server.generate(Request(tokens=reqs[0].tokens[:, :100], max_new=60))
+    assert pool.stats()["parked_bytes"] > parked
+    assert pool.stats()["parked_bytes"] == sum(
+        b.nbytes for bufs in pool._free.values() for b in bufs)
+
+
+def test_pool_parked_bytes_follow_park_lease_and_drop():
+    """``parked_bytes`` is kept as buffers are parked, leased and dropped
+    past ``_MAX_PARKED``; a release with ``reuse=False`` retires."""
+    from repro.launch.serve import KVBucketPool
+
+    pool = KVBucketPool()
+    shape, n = (4, 8), 4 * 8 * 4
+    extra = 3
+    for _ in range(KVBucketPool._MAX_PARKED + extra):
+        pool.release(pool.lease(shape, jnp.float32, zero=True))
+    st = pool.stats()
+    assert st["parked_bytes"] == KVBucketPool._MAX_PARKED * n
+    assert st["retired"] == 0
+    pool.lease(shape, jnp.float32)
+    assert pool.stats()["parked_bytes"] == (KVBucketPool._MAX_PARKED - 1) * n
+    pool.release(pool.lease((2, 2), jnp.float32), reuse=False)
+    st = pool.stats()
+    assert st["retired"] == 1
+    assert st["parked_bytes"] == (KVBucketPool._MAX_PARKED - 1) * n
