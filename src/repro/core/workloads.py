@@ -919,14 +919,15 @@ class DecodeAttentionWorkload(AttentionWorkload):
     length S, so the decode kv-bucket set IS the prefill kv-bucket set
     (lattice-granular, not degenerate — a literal (1, d, S) view makes
     Eq. 2-4 flat in the k-tile and the argmin collapses to the smallest
-    tile, a bucket every 2 tokens).  Only the q block differs at
-    execution: q_len == 1 is static, so the kernel runs block_q == 1 and
-    the lattice m-tile never materializes.  The TRUE number of valid
-    cache rows rides as the ``kv_len`` runtime scalar (a Python int in
-    eager serving, a traced i32 inside a compiled decode step): scores
-    past it are masked and value rows zeroed by the kernel, so the cache
-    tail beyond ``kv_len`` — bucket pad, stale staging bytes, NaNs — can
-    never reach the query row.  Causality needs no flag: the query sits at
+    tile, a bucket every 2 tokens).  Only the q side differs at
+    execution: q_len == 1 is static, so the lattice m-tile never
+    materializes and the kernel takes its decode grid (one step per
+    batch row and kv block, every KV head in the block).  The TRUE
+    number of valid cache rows rides as the ``kv_len`` runtime scalar
+    (a Python int in eager serving, a traced i32 inside a compiled
+    decode step): scores past it are masked and value rows zeroed by the
+    kernel, so the cache tail beyond ``kv_len`` — bucket pad, stale
+    staging bytes, NaNs — can never reach the query row.  Causality needs no flag: the query sits at
     absolute position ``kv_len - 1``, so the key-validity mask IS the
     causal mask; sliding windows re-base through the same offset.
 

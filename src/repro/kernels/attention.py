@@ -18,10 +18,18 @@ masked boundary tiles, never a silently clamped block.
 Supports causal masking, sliding-window attention (h2o-danube, gemma2 local
 layers) and GQA (kv heads shared across query-head groups via the BlockSpec
 index map).  TARGET: TPU; validated on CPU with ``interpret=True``.
+
+Two grids, chosen from the static query length.  Prefill (``sq > 1``)
+takes one grid step per (batch row x query head, q block, kv block).
+Decode (``sq == 1``) has one query per head, so it takes one step per
+(batch row, kv block) with every KV head of the row in the block and
+each KV head's query group as the rows of its q tile: a step moves the
+row's whole K/V block once, not once per query head.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -30,9 +38,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.gemm import interpret_pallas, validate_blocks
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "decode_grid_steps", "decode_vmem_bytes"]
 
 _NEG_INF = -1e30
+_LANES = 128
 
 
 def _attn_kernel(
@@ -115,6 +124,179 @@ def _attn_kernel(
         o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
 
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def decode_vmem_bytes(
+    hkv: int, group: int, block_k: int, d: int, itemsize: int
+) -> int:
+    """VMEM one decode grid step holds, counted in (sublane, lane) tiles.
+
+    Two buffers each of the q, out, K and V blocks; the f32 scratch
+    (running max, sum and accumulator); the f32 scores and weights; and
+    the value block with its rows past ``kv_len`` zeroed.  ``d`` pads to
+    128 lanes and the sublane dims to the dtype's packed tile (16 rows
+    for bf16, 8 for f32).
+    """
+    lanes = _round_up(d, _LANES)
+    sub = 32 // itemsize
+    qo = 2 * _round_up(group, sub) * lanes * itemsize
+    kv = 2 * _round_up(block_k, sub) * lanes * itemsize
+    scratch = (
+        2 * _round_up(hkv, 8) * _round_up(group, _LANES)
+        + hkv * _round_up(group, 8) * lanes
+    ) * 4
+    scores = 2 * hkv * _round_up(group, 8) * _round_up(block_k, _LANES) * 4
+    v_zeroed = hkv * _round_up(block_k, sub) * lanes * itemsize
+    return 2 * hkv * (qo + kv) + scratch + scores + v_zeroed
+
+
+def _decode_kernel(
+    kv_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
+    *, gkv: int, block_k: int, scale: float,
+    causal: bool, window: int | None, softcap: float | None, rows: int,
+):
+    """One (batch row, kv block) step of single-token decode.
+
+    The q tile is ``(hkv, group, d)``: each KV head's query group rides
+    as the M rows of its QK^T, so every K/V block is fetched once for the
+    whole group.  Masking is the prefill kernel's, at one query position
+    per row: keys at or past ``kv_ref[0, row]`` are score-masked and
+    their value rows zeroed, ``causal``/``window`` re-base at the query's
+    absolute position ``kv_ref[1, row]``, and a row with ``kv_len`` 0
+    gives an exact zero output.
+    """
+    kv_i = pl.program_id(1)
+
+    @pl.when(kv_i == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[0]  # (hkv, group, d)
+    k = k_ref[0]  # (hkv, block_k, d)
+    v = v_ref[0]
+    row = pl.program_id(0) if rows > 1 else 0
+    kv_limit = kv_ref[0, row]
+    q_pos = kv_ref[1, row]
+    s = jax.lax.dot_general(
+        q, k, (((2,), (2,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    ) * scale  # (hkv, group, block_k)
+    if softcap is not None:
+        s = jnp.tanh(s / softcap) * softcap
+
+    k_pos = kv_i * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, s.shape, 2
+    )
+    mask = k_pos < kv_limit
+    if causal:
+        mask &= k_pos <= q_pos
+    if window is not None:
+        mask &= q_pos - k_pos < window
+    s = jnp.where(mask, s, _NEG_INF)
+
+    # Zero invalid value rows (0 * NaN would poison the accumulator).
+    v_rows = kv_i * block_k + jax.lax.broadcasted_iota(
+        jnp.int32, v.shape, 1
+    )
+    v = jnp.where(v_rows < kv_limit, v, 0)
+
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new[..., None])
+    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
+    acc_ref[...] = acc_ref[...] * alpha[..., None] + jax.lax.dot_general(
+        p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+        preferred_element_type=jnp.float32,
+    )
+    m_ref[...] = m_new
+
+    @pl.when(kv_i == gkv - 1)
+    def _store():
+        denom = jnp.maximum(l_ref[...], 1e-30)[..., None]
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def _decode_attention(
+    q, k, v, kv_arr, *, block_k, causal, window, softcap, interpret,
+    vmem_limit_bytes,
+):
+    """The ``sq == 1`` grid: ``(b, gkv)``, q reshaped to ``(b, hkv, group,
+    d)`` so each KV head's query group is its q tile."""
+    b, hq, _, d = q.shape
+    _, hkv, skv, _ = k.shape
+    group = hq // hkv
+    need = decode_vmem_bytes(hkv, group, block_k, d, k.dtype.itemsize)
+    if vmem_limit_bytes is not None and need > vmem_limit_bytes:
+        raise ValueError(
+            f"decode attention: a row's block ({hkv} KV heads, group "
+            f"{group}, block_k {block_k}, d {d}) needs {need} bytes of "
+            f"VMEM, over the limit of {vmem_limit_bytes}"
+        )
+    gkv = pl.cdiv(skv, block_k)
+    interpret = interpret_pallas(interpret)
+    if not interpret:
+        # K and V stream from HBM block by block.  Left free, the compiler
+        # may place a whole operand in VMEM inside the op that produces it
+        # (the layer slice of a stacked cache), where that read escapes
+        # this kernel's time and the kernel's speed hangs on whether the
+        # operand happens to fit the VMEM left over.
+        k, v = (
+            pltpu.with_memory_space_constraint(x, pltpu.HBM) for x in (k, v)
+        )
+    kernel = functools.partial(
+        _decode_kernel,
+        gkv=gkv, block_k=block_k, scale=d ** -0.5, causal=causal,
+        window=window, softcap=softcap, rows=kv_arr.shape[1],
+    )
+    q_spec = pl.BlockSpec((1, hkv, group, d), lambda i, j: (i, 0, 0, 0))
+    kv_spec = pl.BlockSpec((1, hkv, block_k, d), lambda i, j: (i, 0, j, 0))
+    out = pl.pallas_call(
+        kernel,
+        grid=(b, gkv),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM), q_spec, kv_spec, kv_spec,
+        ],
+        out_specs=q_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hkv, group, d), q.dtype),
+        scratch_shapes=[
+            pltpu.VMEM((hkv, group), jnp.float32),
+            pltpu.VMEM((hkv, group), jnp.float32),
+            pltpu.VMEM((hkv, group, d), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
+        ),
+        interpret=interpret,
+    )(kv_arr, q.reshape(b, hkv, group, d), k, v)
+    return out.reshape(b, hq, 1, d)
+
+
+def decode_grid_steps(jaxpr) -> int:
+    """Grid steps of every decode-attention launch in ``jaxpr``: each
+    decode ``pallas_call``'s grid size, times the length of every scan it
+    sits in (a layer scan runs its body once per layer)."""
+    steps = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            body = eqn.params["jaxpr"].debug_info.func_name
+            if body == _decode_kernel.__name__:
+                steps += math.prod(eqn.params["grid_mapping"].grid)
+            continue
+        times = eqn.params["length"] if eqn.primitive.name == "scan" else 1
+        for p in eqn.params.values():
+            inner = getattr(p, "jaxpr", None)
+            if inner is not None:
+                inner = getattr(inner, "jaxpr", inner)
+                steps += times * decode_grid_steps(inner)
+    return steps
+
+
 @functools.partial(
     jax.jit,
     static_argnames=(
@@ -154,8 +336,9 @@ def flash_attention(
         0 (self-attention with queries and keys sharing position 0).
       block_q/block_k: Vortex layer-1 tiles for the sequence dims — honored
         verbatim; non-multiple sequence lengths get masked boundary tiles.
-        A decode-shaped call (sq == 1) runs block_q == 1 — the q tile is
-        pinned by the static query length, not the lattice.
+        A decode-shaped call (sq == 1) ignores block_q: it takes the decode
+        grid, one step per (batch row, kv block) with all of the row's KV
+        heads, and raises if that block outgrows ``vmem_limit_bytes``.
       window: sliding-window size (keys within [q-window+1, q]).
       softcap: gemma2-style logit soft-capping applied to QK^T scores.
     Returns (batch, q_heads, seq, head_dim).
@@ -185,6 +368,13 @@ def flash_attention(
         jnp.broadcast_to(kv_vec.reshape(-1), (rows,)),
         jnp.broadcast_to(off_vec.reshape(-1), (rows,)),
     ])
+
+    if sq == 1:
+        return _decode_attention(
+            q, k, v, kv_arr, block_k=block_k, causal=causal, window=window,
+            softcap=softcap, interpret=interpret,
+            vmem_limit_bytes=vmem_limit_bytes,
+        )
 
     qf = q.reshape(b * hq, sq, d)
     kf = k.reshape(b * hkv, skv, d)

@@ -70,6 +70,7 @@ import numpy as np
 from repro.core import AttentionWorkload, DecodeAttentionWorkload, GemmWorkload
 from repro.core.engine import DispatchStats
 from repro.core.hardware import resolve_platform
+from repro.kernels.attention import decode_grid_steps
 from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models.model import abstract_cache, decode_updates_in_place
@@ -348,6 +349,10 @@ class VortexServer:
         # per-row vector — a DIFFERENT XLA artifact, cached separately so
         # the scalar-pos serial path keeps its own executables.
         self._decode_exec_vec: dict[tuple[int, int], jax.stages.Compiled] = {}
+        # Decode-attention grid steps of one launch of each (bp, kvb)
+        # program, read from its traced program (0 where no Pallas decode
+        # kernel runs, as on the CPU's XLA executables).
+        self._decode_grid_steps: dict[tuple[int, int], int] = {}
         # Growable cache leaves are leased from (and returned to) a shared
         # bucket pool instead of churning fresh allocations per growth.
         self.kv_pool = KVBucketPool()
@@ -356,6 +361,7 @@ class VortexServer:
             "decode_compiles": 0, "decode_bucket_hits": 0,
             "chained_prefills": 0,
             "decode_inplace_launches": 0, "decode_restack_launches": 0,
+            "decode_attn_grid_steps": 0,
         }
         # Lazy-chain prefill state: per-(bp, sp) alignment verdicts, the
         # unstacked per-layer params in scan order, and the head matrix.
@@ -491,7 +497,9 @@ class VortexServer:
     def _hand_out(self, programs: dict, bp: int, kvb: int, pos_shape):
         """A decode program for a launch, counted by the path its cache
         update was compiled on: ``decode_inplace_launches`` (the stacked
-        cache is updated in place) or ``decode_restack_launches``."""
+        cache is updated in place) or ``decode_restack_launches``; and by
+        its decode-attention grid steps, summed over layers
+        (``decode_attn_grid_steps``)."""
         key = (bp, kvb)
         if key in programs:
             self.stats["decode_bucket_hits"] += 1
@@ -501,6 +509,7 @@ class VortexServer:
             "decode_inplace_launches" if inplace
             else "decode_restack_launches"
         ] += 1
+        self.stats["decode_attn_grid_steps"] += self._decode_grid_steps[key]
         return exe
 
     def _decode_program(self, programs: dict, bp: int, kvb: int, pos_shape):
@@ -519,13 +528,17 @@ class VortexServer:
                 )
                 self._decode_jits[kvb] = dj
             with self.engine.use():
-                exe = dj.lower(
+                traced = dj.trace(
                     self.params,
                     abstract_cache(self.cfg, bp, kvb),
                     jax.ShapeDtypeStruct((bp, 1), jnp.int32),
                     jax.ShapeDtypeStruct(pos_shape, jnp.int32),
-                ).compile()
+                )
+                exe = traced.lower().compile()
             programs[key] = exe
+            self._decode_grid_steps[key] = decode_grid_steps(
+                traced.jaxpr.jaxpr
+            )
             self.stats["decode_compiles"] += 1
         return exe
 

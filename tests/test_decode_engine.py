@@ -31,7 +31,7 @@ given, settings, st = optional_hypothesis()
 
 from repro.kernels.ref import ref_attention
 from repro.models.layers import _decode_attend
-from repro.vortex import Engine, use
+from repro.vortex import Engine, EngineConfig, use
 
 RNG = np.random.default_rng(23)
 
@@ -390,3 +390,32 @@ def test_server_first_token_comes_from_the_last_prompt_position(
     np.testing.assert_array_equal(first[:, 0], got[:, 0].argmax(-1))
     if prefill == "chained":
         assert server.stats["chained_prefills"] == 2
+
+
+@pytest.mark.parametrize("arch", ["paper-gpt2-124m", "phi4-mini-3.8b"])
+def test_server_counts_decode_attention_grid_steps(mesh, arch):
+    """Each decode launch adds its program's decode-attention grid steps:
+    one per (row, kv block) per layer, with all of the row's heads in the
+    block (group 1 and group 3).  A prefill adds nothing."""
+    from repro.launch.serve import Request, VortexServer
+    from repro.models.registry import get_smoke_config
+
+    cfg = get_smoke_config(arch)
+    server = VortexServer(
+        cfg, mesh, max_cache=256, engine=Engine(EngineConfig(
+            hardware="tpu_v5e", backends=("mxu",), impl="pallas",
+        )),
+    )
+    toks = np.random.default_rng(5).integers(
+        0, cfg.vocab, (3, 40)
+    ).astype(np.int32)
+    server.generate(Request(tokens=toks, max_new=1))  # prefill only
+    assert server.stats["decode_attn_grid_steps"] == 0
+    server.generate(Request(tokens=toks, max_new=4))
+    launches = server.stats["decode_inplace_launches"]
+    assert launches == 3
+    kvb = server.kv_bucket(server.seq_bucket(40))
+    block_k = server._decode_op.select(kvb).strategy.l1[2]
+    rows = server.batch_bucket(3)
+    per_launch = rows * -(-kvb // block_k) * cfg.n_layers
+    assert server.stats["decode_attn_grid_steps"] == launches * per_launch

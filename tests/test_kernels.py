@@ -201,3 +201,49 @@ def test_attention_kernel_blocks_from_vortex_lattice():
     ref = ref_attention(q, q, q)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-3, atol=2e-3)
+
+
+DECODE_CASES = [
+    # (b, hkv, group, d, S, block_k, kv_len, window, softcap, dtype)
+    (3, 2, 1, 64, 128, 32, [0, 128, 45], None, None, jnp.float32),
+    (3, 2, 3, 128, 128, 128, [0, 128, 77], None, None, jnp.float32),
+    (2, 3, 1, 64, 96, 32, 53, None, None, jnp.float32),
+    (2, 2, 3, 128, 128, 128, 128, None, None, jnp.float32),
+    (3, 1, 3, 128, 128, 32, [0, 128, 61], 24, None, jnp.float32),
+    (3, 2, 1, 64, 64, 64, [0, 64, 9], None, 30.0, jnp.float32),
+    (2, 4, 3, 64, 128, 32, 100, 16, 20.0, jnp.float32),
+    (3, 8, 3, 128, 256, 64, [0, 256, 130], None, None, jnp.bfloat16),
+]
+
+
+@pytest.mark.parametrize("causal", [False, True], ids=["masked", "causal"])
+@pytest.mark.parametrize("case", DECODE_CASES)
+def test_flash_attention_decode_grid_matches_ref(case, causal):
+    """A decode-shaped call (sq == 1) takes the decode grid and matches
+    the oracle at each row's own kv_len, with the cache past it
+    NaN-poisoned; a row at kv_len 0 gives an exact zero."""
+    b, hkv, group, d, s, block_k, kv_len, window, softcap, dtype = case
+    hq = hkv * group
+    lens = np.broadcast_to(np.asarray(kv_len, np.int32), (b,))
+    q = _arr((b, hq, 1, d), dtype)
+    k = _arr((b, hkv, s, d), dtype)
+    v = _arr((b, hkv, s, d), dtype)
+    tail = (np.arange(s)[None, :] >= lens[:, None])[:, None, :, None]
+    kv_arg = jnp.asarray(kv_len, jnp.int32)
+    out = flash_attention(
+        q, jnp.where(tail, jnp.nan, k), jnp.where(tail, jnp.nan, v),
+        kv_arg, q_offset=kv_arg - 1, block_q=1, block_k=block_k,
+        causal=causal, window=window, softcap=softcap, interpret=True,
+    )
+    assert out.shape == (b, hq, 1, d)
+    got = np.asarray(out, np.float32)
+    live = lens > 0
+    assert not got[~live].any(), "a row at kv_len 0 must give exact zeros"
+    ref = ref_attention(
+        q, k, v, causal=False, window=window, softcap=softcap,
+        offset=lens - 1, kv_len=lens,
+    )
+    tol = 2e-3 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(
+        got[live], np.asarray(ref, np.float32)[live], rtol=tol, atol=tol,
+    )

@@ -4,8 +4,9 @@ Interpret mode checks neither VMEM nor tiling, so these tests hand the
 kernels to the TPU compiler for a described (unattached) ``v5e:2x2``
 topology: shapes only, nothing runs.  The tiles are the ones the
 ``tpu_v5e`` lattice selects for ``paper-gpt2-124m`` (GEMMs, prefill and
-decode attention) and for a ``granite-moe-1b-a400m`` expert FFN
-(grouped GEMM), at the VMEM limit the engine passes.
+decode attention), for ``phi4-mini-3.8b``'s decode attention and for a
+``granite-moe-1b-a400m`` expert FFN (grouped GEMM), at the VMEM limit the
+engine passes.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may hold the TPU library, and every test worker imports
@@ -29,11 +30,16 @@ from repro.core.workloads import (
     GemmWorkload,
     GroupedGemmWorkload,
 )
-from repro.kernels.attention import flash_attention
+from repro.kernels.attention import (
+    decode_grid_steps,
+    decode_vmem_bytes,
+    flash_attention,
+)
 from repro.kernels.gemm import vortex_gemm
 from repro.kernels.grouped_gemm import vortex_grouped_gemm
 from repro.launch.serve import chain_gemm_sigs
-from repro.models.registry import get_config
+from repro.models.config import SHAPES
+from repro.models.registry import ARCH_IDS, cell_supported, get_config
 from repro.vortex import Engine, EngineConfig
 
 GPT2 = get_config("paper-gpt2-124m")
@@ -50,6 +56,15 @@ def engine():
     return Engine(EngineConfig(
         hardware="tpu_v5e", backends=("mxu",), impl="pallas",
         empirical_levels=(), denylist_persist=False,
+    ))
+
+
+@pytest.fixture(scope="module")
+def served_engine():
+    """The engine ``VortexServer`` builds on a v5e: its decode attention
+    selects the kv block the served decode programs run."""
+    return Engine(EngineConfig(
+        hardware="tpu_v5e", backends=("mxu",), impl="pallas",
     ))
 
 
@@ -140,6 +155,104 @@ def test_decode_attention_compiles_with_per_row_kv_len(engine, one_chip):
         ((b,), jnp.int32),
     )
     assert "tpu_custom_call" in hlo
+
+
+@pytest.mark.parametrize(
+    "widths",
+    [(8, 3, 128, 2048, 8), (12, 1, 64, 1024, 64)],
+    ids=["phi4", "gpt2"],
+)
+def test_decode_grid_compiles_at_served_widths(
+    served_engine, one_chip, widths
+):
+    """The decode grid at the served cells' widths, cache buckets and
+    rows: one step per (row, kv block) with every KV head in the block,
+    compiled for one v5e at the kv block the server selects."""
+    hkv, group, hd, kvb, b = widths
+    kern = served_engine.kernel_for(
+        DecodeAttentionWorkload(seq=None, head_dim=hd)
+    )
+    sel = kern.select(kvb)
+    assert sel.bucket[2] == kvb
+    bk = sel.strategy.l1[2]
+
+    def fn(q, k, v, kv_len):
+        return flash_attention(
+            q, k, v, kv_len, q_offset=kv_len - 1, block_q=1, block_k=bk,
+            causal=False, interpret=False,
+            vmem_limit_bytes=kern.vmem_limit_bytes,
+        )
+
+    shapes = (
+        ((b, hkv * group, 1, hd), jnp.bfloat16),
+        ((b, hkv, kvb, hd), jnp.bfloat16), ((b, hkv, kvb, hd), jnp.bfloat16),
+        ((b,), jnp.int32),
+    )
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    steps = decode_grid_steps(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert steps == b * (kvb // bk)
+    assert "tpu_custom_call" in _compile(one_chip, fn, *shapes)
+
+
+def test_decode_grid_streams_kv_from_hbm(one_chip):
+    """K and V reach the decode kernel in HBM.  A layer sliced out of a
+    stacked cache at phi4-batch's widths (32 MiB) fits the VMEM the
+    kernel leaves over, and the compiler would otherwise stage it whole
+    in VMEM inside the slice, outside the kernel."""
+    b, hkv, group, hd, kvb = 8, 8, 3, 128, 2048
+
+    def fn(q, k_stack, v_stack, layer, kv_len):
+        k, v = (
+            jax.lax.dynamic_index_in_dim(x, layer, 0, keepdims=False)
+            for x in (k_stack, v_stack)
+        )
+        return flash_attention(
+            q, k, v, kv_len, q_offset=kv_len - 1, block_q=1, block_k=512,
+            causal=False, interpret=False, vmem_limit_bytes=96 * 2**20,
+        )
+
+    hlo = _compile(
+        one_chip, fn, ((b, hkv * group, 1, hd), jnp.bfloat16),
+        ((4, b, hkv, kvb, hd), jnp.bfloat16),
+        ((4, b, hkv, kvb, hd), jnp.bfloat16),
+        ((), jnp.int32), ((b,), jnp.int32),
+    )
+    (call,) = re.findall(
+        r"%flash_attention\S* = .*? custom-call\(([^)]*)\)", hlo
+    )
+    kv_operands = [o.strip().lstrip("%") for o in call.split(",")][2:]
+    for name in kv_operands:
+        (shape,) = re.findall(rf"%{re.escape(name)} = (\S+) ", hlo)
+        assert str(kvb) in shape and "S(1)" not in shape, (name, shape)
+
+
+SERVED_ATTN = [
+    a for a in ("paper-gpt2-124m", *ARCH_IDS)
+    if any(spec.mixer == "attn" for spec in get_config(a).pattern)
+]
+
+
+@pytest.mark.parametrize("arch", SERVED_ATTN)
+def test_decode_row_block_fits_the_vmem_limit(served_engine, arch):
+    """Every registered configuration's decode block, all of a row's KV
+    heads at the selected kv block, fits the VMEM limit the engine passes
+    at every kv bucket it decodes at: to the registry's 32k decode shape,
+    and to 512k where the configuration serves that shape."""
+    cfg = get_config(arch)
+    hkv, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    group = cfg.n_heads // hkv
+    kern = served_engine.kernel_for(
+        DecodeAttentionWorkload(seq=None, head_dim=hd)
+    )
+    longest = SHAPES[
+        "long_500k" if cell_supported(arch, "long_500k")[0] else "decode_32k"
+    ].seq_len
+    kv = 1024
+    while kv <= longest:
+        bk = kern.select(kv).strategy.l1[2]
+        need = decode_vmem_bytes(hkv, group, bk, hd, 2)
+        assert need <= kern.vmem_limit_bytes, (kv, bk, need)
+        kv *= 2
 
 
 @pytest.mark.parametrize(
