@@ -434,81 +434,6 @@ def _bench_continuous_batching(smoke: bool) -> dict:
     return out
 
 
-def _bench_prefill_chain(smoke: bool) -> dict:
-    """The chained-prefill serving section (DESIGN.md §8): whole-model
-    prefills through launch/serve.py's lazy handle chain, reporting the
-    boundary-copy contract — zero interior unstage+restage pairs at a
-    chain-aligned bucket, every engine boundary forwarded — plus
-    bit-identity vs the eager per-op reference (identical dispatch
-    sequence on plain arrays).  CI gates boundary_copies_per_block <= 1,
-    forwarded_per_prefill >= 1 and bit_identical_to_eager."""
-    from jax.sharding import Mesh
-    from repro.launch.serve import VortexServer
-    from repro.models.registry import get_smoke_config
-
-    cfg = get_smoke_config("paper-gpt2-124m")
-    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("data", "model"))
-    server = VortexServer(cfg, mesh, max_cache=256, prefill="chained")
-    rng = np.random.default_rng(29)
-    bp, s = 1, 100
-    sp = server.chain_seq_bucket(s, bp)
-    tokens = rng.integers(0, cfg.vocab, (bp, s)).astype(np.int32)
-    batch = server._make_batch(bp, sp, tokens)
-
-    def chain_counters() -> dict:
-        keys = (
-            "stage_copies", "unstage_copies", "realize_slices", "forwarded",
-        )
-        out = dict.fromkeys(keys, 0)
-        for kind, st in server.engine.stats().items():
-            if kind == "calibration":  # engine-level section, not a kind
-                continue
-            for k in keys:
-                out[k] += st[k]
-        return out
-
-    # Warm the per-bucket executables, then count over ONE prefill.
-    last, cache = server.prefill_chained(bp, sp, batch)
-    before = chain_counters()
-    last, cache = server.prefill_chained(bp, sp, batch)
-    after = chain_counters()
-    copies = sum(
-        after[k] - before[k]
-        for k in ("stage_copies", "unstage_copies", "realize_slices")
-    )
-    forwarded = after["forwarded"] - before["forwarded"]
-    blocks = cfg.n_layers
-
-    last_e, cache_e = server.prefill_chained(bp, sp, batch, eager=True)
-    max_abs = max(
-        float(np.max(np.abs(
-            np.asarray(a, np.float32) - np.asarray(b, np.float32)
-        )))
-        for a, b in zip(
-            jax.tree_util.tree_leaves((last, cache)),
-            jax.tree_util.tree_leaves((last_e, cache_e)),
-        )
-    )
-
-    times = []
-    for _ in range(3 if smoke else 10):
-        t0 = time.perf_counter()
-        jax.block_until_ready(server.prefill_chained(bp, sp, batch)[0])
-        times.append(time.perf_counter() - t0)
-
-    return {
-        "seq_bucket": sp,
-        "batch_bucket": bp,
-        "blocks_per_prefill": blocks,
-        "chain_aligned": server._chain_aligned(bp, sp),
-        "boundary_copies_per_block": copies / max(blocks, 1),
-        "forwarded_per_prefill": forwarded,
-        "us_per_prefill": min(times) * 1e6,
-        "max_abs_diff_vs_eager": max_abs,
-        "bit_identical_to_eager": max_abs == 0.0,
-    }
-
-
 def _bench_moe(smoke: bool) -> dict:
     """The MoE serving section: a granite_moe-shaped expert-FFN layer
     served engine-vs-dense.  With a session installed, ``_expert_ffn``
@@ -682,9 +607,8 @@ def serving_payload(smoke: bool) -> dict:
     """The BENCH_serving.json payload (benchmarks/run.py --json): dispatch
     overhead on unseen shapes, the aligned-vs-unaligned hot-path ratio and
     copies/launches per call (with raw per-round samples), the serving
-    decode contract, the chained-prefill boundary-copy contract, and the
-    MoE grouped-GEMM contract (one launch per projection for all
-    experts)."""
+    decode contract, and the MoE grouped-GEMM contract (one launch per
+    projection for all experts)."""
     hardware = "host_cpu"
     eng = Engine(hardware, empirical_levels=(() if smoke else None))
     hw = get_hardware(hardware)
@@ -708,7 +632,6 @@ def serving_payload(smoke: bool) -> dict:
         "dispatch": _bench_dispatch(eng, hw, smoke),
         "hot_path": _bench_hot_path(smoke),
         "decode": _bench_decode(smoke),
-        "prefill_chain": _bench_prefill_chain(smoke),
         "continuous_batching": _bench_continuous_batching(smoke),
         "moe": _bench_moe(smoke),
     }

@@ -2,12 +2,12 @@
 """Bring-up check: serve paper-gpt2-124m at full width on one TPU chip.
 
 Drives the main path once, in this one process, through the entry points a
-user calls: ``VortexServer.generate()`` with the whole-program (``aot``)
-prefill and with the ``chained`` prefill (every GEMM an eager engine
-dispatch), then ``ContinuousScheduler`` with concurrent requests, then the
-``vortex.ops`` kernels directly.  The model is the published width
-(12 layers, d_model 768, 12 heads, vocab 50257, bf16) with random weights
-from ``SEED`` and GPT-2's context, ``max_cache=1024``.  It checks that:
+user calls: ``VortexServer.generate()`` (one AOT prefill program per
+bucket, then the decode programs), then ``ContinuousScheduler`` with
+concurrent requests, then the ``vortex.ops`` kernels directly.  The model
+is the published width (12 layers, d_model 768, 12 heads, vocab 50257,
+bf16) with random weights from ``SEED`` and GPT-2's context,
+``max_cache=1024``.  It checks that:
 
   * the engine runs compiled Pallas kernels (``impl="pallas"``, not
     interpreted), and the prefill/decode programs hold ``tpu_custom_call``;
@@ -222,37 +222,24 @@ def main() -> int:
 
     with phase("warmup", times):
         # Compile exactly the buckets the requests below touch, by serving
-        # each request shape once: aot and chained prefill, then the
-        # scheduler's admissions and mixed-progress decode steps.
-        for mode in ("aot", "chained"):
-            server.prefill = mode
-            for req in requests():
-                server.generate(req)
-        server.prefill = "aot"
+        # each request shape once, then the scheduler's admissions and
+        # mixed-progress decode steps.
+        for req in requests():
+            server.generate(req)
         run_scheduler(sched_requests())
         print(f"warmup: prefill_compiles={server.stats['prefill_compiles']} "
               f"decode_compiles={server.stats['decode_compiles']}")
 
-    outs = {}
-    for mode in ("aot", "chained"):
-        with phase(f"serve_{mode}", times):
-            server.prefill = mode
-            outs[mode] = []
-            for (b, s), req in zip(size.requests, requests()):
-                t0 = time.perf_counter()
-                out = server.generate(req)
-                dt = time.perf_counter() - t0
-                check(out.shape == (b, size.max_new), f"shape {out.shape}")
-                check(bool(((out >= 0) & (out < cfg.vocab)).all()),
-                      "token outside the vocabulary")
-                outs[mode].append(out)
-                print(f"  generate batch={b} prompt={s} "
-                      f"max_new={size.max_new}: {dt:.3f}s")
-    same = sum(
-        int(np.array_equal(a, c)) for a, c in zip(outs["aot"], outs["chained"])
-    )
-    print(f"aot vs chained greedy tokens identical: {same}/{len(outs['aot'])}")
-    server.prefill = "aot"
+    with phase("serve", times):
+        for (b, s), req in zip(size.requests, requests()):
+            t0 = time.perf_counter()
+            out = server.generate(req)
+            dt = time.perf_counter() - t0
+            check(out.shape == (b, size.max_new), f"shape {out.shape}")
+            check(bool(((out >= 0) & (out < cfg.vocab)).all()),
+                  "token outside the vocabulary")
+            print(f"  generate batch={b} prompt={s} "
+                  f"max_new={size.max_new}: {dt:.3f}s")
 
     with phase("scheduler", times):
         sched, results = run_scheduler(sched_requests())
@@ -282,19 +269,16 @@ def main() -> int:
             ref = reference(server.params, jnp.asarray(toks), s + n_dec)
             ref = np.asarray(ref[:, s - 1:, :cfg.vocab], np.float32)
             ref_max = float(np.max(np.abs(ref)))
-            for mode in ("aot", "chained"):
-                server.prefill = mode
-                got = server.score(toks, s)[..., :cfg.vocab]
-                e_pre = float(np.max(np.abs(got[:, 0] - ref[:, 0])))
-                e_dec = float(np.max(np.abs(got[:, 1:] - ref[:, 1:])))
-                bound = LOGIT_REL_BOUND * max(1.0, ref_max)
-                print(f"  logits batch={b} prompt={s} prefill={mode}: "
-                      f"max_abs_err prefill={e_pre:.6g} "
-                      f"decode({n_dec} teacher-forced)={e_dec:.6g} "
-                      f"max|ref|={ref_max:.6g} bound={bound:.6g}")
-                check(max(e_pre, e_dec) <= bound,
-                      f"logits off the reference by {max(e_pre, e_dec)}")
-        server.prefill = "aot"
+            got = server.score(toks, s)[..., :cfg.vocab]
+            e_pre = float(np.max(np.abs(got[:, 0] - ref[:, 0])))
+            e_dec = float(np.max(np.abs(got[:, 1:] - ref[:, 1:])))
+            bound = LOGIT_REL_BOUND * max(1.0, ref_max)
+            print(f"  logits batch={b} prompt={s}: "
+                  f"max_abs_err prefill={e_pre:.6g} "
+                  f"decode({n_dec} teacher-forced)={e_dec:.6g} "
+                  f"max|ref|={ref_max:.6g} bound={bound:.6g}")
+            check(max(e_pre, e_dec) <= bound,
+                  f"logits off the reference by {max(e_pre, e_dec)}")
 
     with phase("ops_vs_oracle", times):
         key = jax.random.PRNGKey(SEED)

@@ -53,13 +53,11 @@ from repro.runtime import faults
 
 __all__ = [
     "DispatchStats",
-    "LazyBucket",
     "OfflineStats",
     "PrecompileError",
     "VortexKernel",
     "VortexGemm",
     "VortexEngine",
-    "lazy_map",
 ]
 
 
@@ -107,12 +105,6 @@ class DispatchStats:
     arrived as tracers inside an enclosing jit (they become part of the
     surrounding program, not runtime launches).
 
-    ``forwarded`` counts :class:`LazyBucket` operands whose buffer entered
-    the next program directly — an op boundary crossed with NO unstage and
-    NO restage; ``realize_slices`` counts deferred output slices forced by
-    a non-engine consumer (``LazyBucket.realize``).  Whole-chain boundary
-    traffic is exactly ``stage_copies + unstage_copies + realize_slices``.
-
     ``quarantined`` counts candidates the degradation ladder denylisted
     after a precompile/launch failure; ``fallbacks`` counts dispatches
     that exhausted the lattice retries and ran the XLA reference rung.
@@ -127,8 +119,6 @@ class DispatchStats:
     unstage_copies: int = 0
     padded_calls: int = 0
     traced_calls: int = 0
-    forwarded: int = 0
-    realize_slices: int = 0
     fallbacks: int = 0
     quarantined: int = 0
 
@@ -143,164 +133,6 @@ def _stage_into(buf, x):
     tail keeps whatever stale bytes it held — the masked-tail kernels never
     read them — and no fresh zero-filled allocation is made."""
     return jax.lax.dynamic_update_slice(buf, x, (0,) * buf.ndim)
-
-
-class LazyBucket:
-    """A bucket-shaped engine result that has NOT been sliced to its true
-    extent: ``buffer`` is the raw per-bucket program output (rows past
-    ``extent`` along ``axis`` hold garbage the masked-tail contract never
-    reads), ``extent`` is the true dynamic size.
-
-    ``.shape`` reports the TRUE shape, so workload ``bind``/``dispatch_key``
-    /``dynamic_extent`` hooks (which only read ``.shape``/``.dtype``) treat
-    a handle exactly like the realized array.  Realization — the deferred
-    output slice — happens once, lazily: when a non-engine consumer forces
-    it via :meth:`realize` or the ``__jax_array__`` protocol.  An engine
-    dispatch whose operand is a handle in a compatible bucket skips it
-    entirely and consumes ``buffer`` directly (``DispatchStats.forwarded``).
-
-    Handles are eager-only plumbing between dispatches; they are not pytree
-    leaves and must not cross a ``jit`` boundary unrealized.
-    """
-
-    __slots__ = ("buffer", "extent", "axis", "_stats", "_lock", "_realized")
-
-    def __init__(self, buffer, extent, axis, stats=None, lock=None):
-        self.buffer = buffer
-        self.extent = int(extent)
-        self.axis = axis
-        self._stats = stats
-        self._lock = lock
-        self._realized = None
-
-    # -- array-protocol surface (what shape-reading hooks consume) ---------
-
-    @property
-    def shape(self) -> tuple:
-        s = list(self.buffer.shape)
-        s[self.axis] = self.extent
-        return tuple(s)
-
-    @property
-    def dtype(self):
-        return self.buffer.dtype
-
-    @property
-    def ndim(self) -> int:
-        return self.buffer.ndim
-
-    @property
-    def padded_extent(self) -> int:
-        """The bucket size the buffer is shaped to along ``axis``."""
-        return self.buffer.shape[self.axis]
-
-    @property
-    def is_aligned(self) -> bool:
-        return self.padded_extent == self.extent
-
-    def _count_slice(self) -> None:
-        if self._stats is not None:
-            if self._lock is not None:
-                with self._lock:
-                    self._stats.realize_slices += 1
-            else:
-                self._stats.realize_slices += 1
-
-    def realize(self) -> jax.Array:
-        """The true-extent array (the deferred unstage).  Identity for an
-        aligned bucket; otherwise ONE counted slice, cached so repeated
-        forcing pays once."""
-        if self._realized is None:
-            if self.is_aligned:
-                self._realized = self.buffer
-            else:
-                self._realized = jax.lax.slice_in_dim(
-                    self.buffer, 0, self.extent, axis=self.axis
-                )
-                self._count_slice()
-        return self._realized
-
-    def __jax_array__(self) -> jax.Array:
-        return self.realize()
-
-    def rewrap(self, buffer, extent=None, axis=None) -> "LazyBucket":
-        """A new handle over ``buffer`` sharing this handle's copy
-        accounting — for extent-preserving reshapes/transposes between
-        dispatches (split/merge heads, flattening batch into rows)."""
-        return LazyBucket(
-            buffer,
-            self.extent if extent is None else extent,
-            self.axis if axis is None else axis,
-            self._stats,
-            self._lock,
-        )
-
-    def map(self, fn) -> "LazyBucket":
-        """Apply a ROW-LOCAL ``fn`` (output row i depends only on input row
-        i along ``axis``) to the raw buffer: garbage tail rows stay confined
-        past ``extent``.  The handle's bucket geometry must survive."""
-        out = fn(self.buffer)
-        if out.shape[self.axis] != self.padded_extent:
-            raise ValueError(
-                f"map changed the bucket axis: {self.padded_extent} -> "
-                f"{out.shape[self.axis]}"
-            )
-        return self.rewrap(out)
-
-    def clamp(self, padded: int) -> "LazyBucket":
-        """This handle re-bucketed to ``padded`` rows along ``axis`` (true
-        extent unchanged).  Identity when already that size; otherwise one
-        counted boundary slice — how chain drivers pin a dispatch output
-        that came back in a larger bucket to the chain's width."""
-        if self.padded_extent == padded:
-            return self
-        if padded < self.extent:
-            raise ValueError(
-                f"cannot clamp below the true extent: {padded} < "
-                f"{self.extent}"
-            )
-        buf = jax.lax.slice_in_dim(self.buffer, 0, padded, axis=self.axis)
-        self._count_slice()
-        return self.rewrap(buf)
-
-    def __repr__(self) -> str:
-        return (
-            f"LazyBucket(shape={self.shape}, padded_extent="
-            f"{self.padded_extent}, axis={self.axis}, dtype={self.dtype})"
-        )
-
-
-def lazy_map(fn, *xs):
-    """Apply an elementwise/row-local ``fn`` across arrays and LazyBuckets
-    without realizing: the chain glue for the non-engine ops between
-    dispatches (norms, residual adds, activations).
-
-    ``fn`` must be ROW-LOCAL along the handles' bucket axis.  All handle
-    operands must share (axis, padded_extent) — then ``fn`` runs on the raw
-    buffers and the result is re-wrapped (extent = min of the operands', so
-    any row past a partial operand's extent is conservatively garbage).
-    Incompatible handles fall back to realizing everything (counted).
-    Plain-array operands must broadcast against the BUFFER shape (e.g.
-    per-feature norm weights).  With no handle operands this is ``fn(*xs)``.
-    """
-    handles = [x for x in xs if isinstance(x, LazyBucket)]
-    if not handles:
-        return fn(*xs)
-    ref = handles[0]
-    if any(
-        h.axis != ref.axis or h.padded_extent != ref.padded_extent
-        for h in handles[1:]
-    ):
-        return fn(
-            *(x.realize() if isinstance(x, LazyBucket) else x for x in xs)
-        )
-    out = fn(*(x.buffer if isinstance(x, LazyBucket) else x for x in xs))
-    if out.shape[ref.axis] != ref.padded_extent:
-        raise ValueError(
-            "lazy_map fn changed the bucket axis: "
-            f"{ref.padded_extent} -> {out.shape[ref.axis]}"
-        )
-    return ref.rewrap(out, extent=min(h.extent for h in handles))
 
 
 class _StagingPool:
@@ -608,7 +440,7 @@ class VortexKernel:
                         raise PrecompileError(self._wl.kind, sel, e) from e
         return len(sels)
 
-    def __call__(self, *args, lazy: bool = False):
+    def __call__(self, *args):
         """Dynamic-shape dispatch through the masked-tail staging contract.
 
         Select on the runtime extent, then launch the ONE fused per-bucket
@@ -628,43 +460,20 @@ class VortexKernel:
         the surrounding program, and engine-owned buffers must not be
         captured by a trace.
 
-        :class:`LazyBucket` operands at positions the workload declares in
-        ``consumes_staged`` forward their bucket buffer into the program
-        directly (``_call_forwarded``): no unstage of the producer, no
-        restage here when the buckets agree.  Handles at any other
-        position realize first (one counted slice).  With ``lazy=True``
-        the output is returned as a LazyBucket instead of being finalized
-        — best-effort: reference-path calls (tracers, staging disabled)
-        still return plain finalized arrays, so chain drivers must accept
-        both.
-
         A candidate that raises at executable build or launch walks the
         degradation ladder (``_degrade``): quarantine, re-select the
         next-best lattice candidate, retry up to ``max_retries``, then the
         XLA reference rung — the call still returns a correct result
         whenever any rung works.
         """
-        wl = self._wl
-        if any(isinstance(a, LazyBucket) for a in args):
-            fwd = wl.consumes_staged if self._staging else {}
-            args = tuple(
-                a.realize()
-                if isinstance(a, LazyBucket) and i not in fwd else a
-                for i, a in enumerate(args)
-            )
-            handles = {
-                i for i, a in enumerate(args) if isinstance(a, LazyBucket)
-            }
-            if handles:
-                return self._call_forwarded(args, handles, lazy)
-        m = wl.dynamic_extent(*args)
+        m = self._wl.dynamic_extent(*args)
         sel = self._select_healthy(m)
         try:
-            return self._dispatch(sel, m, args, lazy)
+            return self._dispatch(sel, args)
         except Exception as exc:
-            return self._degrade(m, sel, args, lazy, exc)
+            return self._degrade(m, sel, args, exc)
 
-    def _dispatch(self, sel: Selection, m: int, args: tuple, lazy: bool):
+    def _dispatch(self, sel: Selection, args: tuple):
         """One dispatch attempt at a fixed Selection (the ladder's rung
         body; exactly the pre-ladder dispatch path)."""
         wl = self._wl
@@ -680,7 +489,6 @@ class VortexKernel:
                 st.calls += 1
                 st.traced_calls += 1
             return self._call_padded(sel, entry, args, view)
-        lazy_out = lazy and wl.staged_out_axis is not None
         scalars = wl.runtime_scalars(sel, *view)
         shapes = wl.staged_shapes(sel, *view)
         unaligned = [
@@ -693,10 +501,6 @@ class VortexKernel:
                 st.aligned_calls += 1
                 st.launches += 1
             out = entry.run(*view, *scalars)
-            if lazy_out:
-                return LazyBucket(
-                    out, m, wl.staged_out_axis, st, self._stats_lock
-                )
             return wl.finalize(sel, out, *args)
         # Check a buffer set out of the entry's pool: staging and the
         # launch run with NO entry-wide lock, so concurrent same-bucket
@@ -714,10 +518,7 @@ class VortexKernel:
             st.unaligned_calls += 1
             st.stage_copies += len(unaligned)
             st.launches += 1
-            # A lazy output defers the unstage slice: it is only paid (and
-            # counted, as realize_slices) if a non-engine consumer forces
-            # the handle.
-            if wl.unstages and not lazy_out:
+            if wl.unstages:
                 st.unstage_copies += 1
         try:
             out = entry.run(*staged, *scalars)
@@ -727,9 +528,6 @@ class VortexKernel:
             # buffer set — the staged buffers stay valid (the launch does
             # not donate them), so they go straight back into rotation.
             entry.pool.release(bufs)
-        if lazy_out:
-            return LazyBucket(out, m, wl.staged_out_axis, st,
-                              self._stats_lock)
         return wl.finalize(sel, out, *args)
 
     # -- degradation ladder (DESIGN.md §11) ---------------------------------
@@ -766,8 +564,7 @@ class VortexKernel:
         return True
 
     def _degrade(
-        self, m: int, sel: Selection, args: tuple, lazy: bool,
-        exc: Exception,
+        self, m: int, sel: Selection, args: tuple, exc: Exception
     ):
         """Walk the ladder after ``sel`` failed: quarantine it, re-select
         the next-best lattice candidate excluding quarantined entries,
@@ -788,7 +585,7 @@ class VortexKernel:
             if nxt is None:
                 break  # lattice exhausted: straight to the reference rung
             try:
-                out = self._dispatch(nxt, m, args, lazy)
+                out = self._dispatch(nxt, args)
             except Exception as e:
                 exc = e
                 if self._quarantine(nxt):
@@ -834,95 +631,6 @@ class VortexKernel:
             self.dispatch_stats.calls += 1
             self.dispatch_stats.fallbacks += 1
         return self._call_padded(sel, entry, args)
-
-    def _call_forwarded(self, args: tuple, handles: set, lazy: bool):
-        """Bucket-to-bucket dispatch: LazyBucket operands hand their raw
-        bucket buffers to the program, the true extents ride in the runtime
-        scalars.  Selection happens at the PADDED extent (the buffers' own
-        bucket), so a producer and consumer sharing a bucket forward with
-        zero copies; a handle whose buffer does not match this selection's
-        staged shape restages (counted stage copy) — correct either way,
-        because staged tails are garbage by contract and every mask scalar
-        is computed from the TRUE shapes.
-
-        ``consumes_staged`` positions are call-arg positions; only
-        identity-``stage_view`` workloads declare any, so view index ==
-        arg index throughout.
-        """
-        wl = self._wl
-        st = self.dispatch_stats
-
-        def realize_all():
-            flat = tuple(
-                a.realize() if isinstance(a, LazyBucket) else a for a in args
-            )
-            return self(*flat, lazy=lazy)
-
-        raw = tuple(
-            a.buffer if isinstance(a, LazyBucket) else a for a in args
-        )
-        true = tuple(
-            jax.ShapeDtypeStruct(a.shape, a.dtype)
-            if isinstance(a, LazyBucket) else a
-            for a in args
-        )
-        view = wl.stage_view(*raw)
-        if any(isinstance(a, jax.core.Tracer) for a in view):
-            return realize_all()  # forwarding is eager-only
-        try:
-            m_disp = wl.dynamic_extent(*raw)
-            m_true = wl.dynamic_extent(*true)
-        except AssertionError:
-            # Mixed handle/plain operands whose padded vs true extents the
-            # workload refuses to reconcile (attention's q/kv seq match).
-            return realize_all()
-        sel = self.selector.select(m_disp)
-        entry = self._entry_for(sel, raw)
-        scalars = wl.runtime_scalars(sel, *wl.stage_view(*true))
-        shapes = wl.staged_shapes(sel, *view)
-        unaligned = [
-            i for i, s in enumerate(shapes)
-            if s is not None and view[i].shape != s
-        ]
-        lazy_out = lazy and wl.staged_out_axis is not None
-        slices_out = (
-            wl.unstages and not lazy_out and wl.dynamic_bucket(sel) != m_true
-        )
-        if not unaligned:
-            with self._stats_lock:
-                st.calls += 1
-                st.aligned_calls += 1
-                st.launches += 1
-                st.forwarded += len(handles)
-                if slices_out:
-                    st.unstage_copies += 1
-            out = entry.run(*view, *scalars)
-        else:
-            need = {i: (shapes[i], view[i].dtype) for i in unaligned}
-            bufs = entry.pool.acquire(need)
-            staged = list(view)
-            for i in unaligned:
-                # Restaging a handle writes its WHOLE buffer — garbage tail
-                # included — into the larger bucket; safe, since the
-                # scalars above mask at the true extents.
-                buf = _stage_into(bufs[i], view[i])
-                bufs[i] = buf
-                staged[i] = buf
-            with self._stats_lock:
-                st.calls += 1
-                st.unaligned_calls += 1
-                st.stage_copies += len(unaligned)
-                st.launches += 1
-                st.forwarded += len(handles - set(unaligned))
-                if slices_out:
-                    st.unstage_copies += 1
-            out = entry.run(*staged, *scalars)
-            entry.pool.release(bufs)
-        if lazy_out:
-            return LazyBucket(
-                out, m_true, wl.staged_out_axis, st, self._stats_lock
-            )
-        return wl.finalize(sel, out, *true)
 
     def _call_padded(self, sel, entry, args, view=None) -> jax.Array:
         """The zero-pad reference path: functionally identical to staging

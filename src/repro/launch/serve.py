@@ -39,26 +39,12 @@ fallbacks (always 0) — surfaced by ``engine_dispatch_stats()`` under
 decode programs (warming the engine's attention executables through the
 session) before traffic arrives.
 
-``prefill="chained"`` swaps the AOT prefill program for the lazy-handle
-chain (DESIGN.md §8): the whole model runs eagerly through the engine
-session with every dispatch output staying a bucket-shaped
-:class:`~repro.core.engine.LazyBucket` that the next dispatch consumes
-directly — at a chain-aligned sequence bucket (``chain_seq_bucket``) a
-prefill performs ZERO interior unstage+restage pairs, and the decode
-cache's k/v leaves consume the attention projections' bucket buffers
-without a copy.  The eager per-op reference (``prefill_chained(...,
-eager=True)``) runs the identical dispatch sequence on plain arrays and
-is bit-identical; the AOT path stays the default and the fallback for
-unsupported architectures.
-
 ``python -m repro.launch.serve --arch paper-gpt2-124m --smoke --requests 16``
 """
 from __future__ import annotations
 
 import argparse
 import dataclasses
-import functools
-import math
 import threading
 import time
 from typing import Any
@@ -83,7 +69,6 @@ from repro.vortex import CompiledOp, Engine, EngineConfig, pow2_bucket
 
 __all__ = [
     "VortexServer",
-    "chain_gemm_sigs",
     "Request",
     "KVBucketPool",
     "RequestError",
@@ -91,22 +76,6 @@ __all__ = [
     "DeadlineExceeded",
     "CacheOverflowError",
 ]
-
-
-def chain_gemm_sigs(cfg) -> list[tuple[int, int]]:
-    """Every (K, N) GEMM signature the chained prefill dispatches for
-    ``cfg``: q/k/v/o projections, the MLP pair, and the LM head."""
-    d, hd = cfg.d_model, cfg.resolved_head_dim
-    sigs = {
-        (d, cfg.n_heads * hd),        # wq
-        (d, cfg.n_kv_heads * hd),     # wk / wv
-        (cfg.n_heads * hd, d),        # wo
-        (d, cfg.vocab_padded),        # lm head
-    }
-    if any(spec.mlp == "dense" for spec in cfg.pattern):
-        sigs.add((d, cfg.d_ff))       # w_in / w_gate
-        sigs.add((cfg.d_ff, d))       # w_out
-    return sorted(sigs)
 
 
 class CacheOverflowError(ValueError):
@@ -295,11 +264,10 @@ class VortexServer:
         engine: Engine | None = None,
         prefill: str = "aot",
     ):
-        if prefill not in ("aot", "chained"):
-            raise ValueError(
-                f"prefill must be 'aot' or 'chained', got {prefill!r}"
-            )
-        self.prefill = prefill
+        # The AOT program is the one prefill path; the keyword survives for
+        # callers that still name it.
+        if prefill != "aot":
+            raise ValueError(f"prefill must be 'aot', got {prefill!r}")
         self.cfg = cfg
         self.rules = make_rules(
             mesh, n_heads=cfg.n_heads, n_kv_heads=cfg.n_kv_heads
@@ -359,16 +327,11 @@ class VortexServer:
         self.stats = {
             "prefill_compiles": 0, "bucket_hits": 0,
             "decode_compiles": 0, "decode_bucket_hits": 0,
-            "chained_prefills": 0,
             "decode_inplace_launches": 0, "decode_restack_launches": 0,
             "decode_attn_grid_steps": 0,
         }
-        # Lazy-chain prefill state: per-(bp, sp) alignment verdicts, the
-        # unstacked per-layer params in scan order, and the head matrix.
-        self._chain_aligned_cache: dict[tuple[int, int], bool] = {}
+        # Per-sequence-bucket verdicts of ``_attn_aligned``.
         self._attn_aligned_cache: dict[int, bool] = {}
-        self._chain_layer_cache: list | None = None
-        self._head_cache: jax.Array | None = None
         # Per-token decode accounting (the padding-free decode contract):
         # one launch per token, zero pad fallbacks, a stage copy only when
         # the cache grows into the next kv bucket.
@@ -587,7 +550,7 @@ class VortexServer:
         kv pool (attention k/v reuse parked buffers as-is — their stale
         tails sit past kv_len and are never read; MLA's ckv/k_rope lease
         fresh zeros, see ``_POOLED_STALE_OK``) and the outgrown leaves are
-        released back, so chained growth recycles instead of churning.
+        released back, so repeated growth recycles instead of churning.
 
         Growth is TWO-PHASE for failure isolation: every new leaf is
         leased and copied first, and the outgrown leaves are released only
@@ -636,20 +599,7 @@ class VortexServer:
         st.stage_copies += len(old_leaves)
         return out_cache
 
-    # -- lazy-handle chained prefill ----------------------------------------
-
-    def _prefill_chained_supported(self) -> bool:
-        """True when every layer of the architecture runs through the lazy
-        handle chain (plain attn mixer, dense/none MLP, no cross-attention,
-        no vision prefix / encoder stack)."""
-        cfg = self.cfg
-        if cfg.vision_prefix or cfg.encoder_decoder:
-            return False
-        return all(
-            spec.mixer == "attn" and spec.mlp in ("dense", "none")
-            and not spec.cross_attn
-            for spec in cfg.pattern
-        )
+    # -- prefill sequence buckets -------------------------------------------
 
     def _attn_aligned(self, sp: int) -> bool:
         """True when prefill attention at sequence bucket ``sp`` runs on its
@@ -673,41 +623,18 @@ class VortexServer:
             self._attn_aligned_cache[sp] = hit
         return hit
 
-    def _chain_aligned(self, bp: int, sp: int) -> bool:
-        """True when EVERY dispatch of a (bp, sp) chained prefill lands on
-        its own bucket: each chain GEMM's selection at m = bp*sp pads to
-        exactly bp*sp, and attention is aligned at sp (``_attn_aligned``)
-        — so handles forward bucket-to-bucket with zero boundary copies end
-        to end."""
-        key = (bp, sp)
-        hit = self._chain_aligned_cache.get(key)
-        if hit is None:
-            eng, m = self.engine, bp * sp
-            hit = self._attn_aligned(sp) and all(
-                eng.kernel_for(
-                    GemmWorkload(M=None, N=n, K=k)
-                ).select(m).padded_m == m
-                for k, n in chain_gemm_sigs(self.cfg)
-            )
-            self._chain_aligned_cache[key] = hit
-        return hit
-
-    def _first_aligned(self, s: int, aligned) -> int:
-        """The first engine bucket >= seq_bucket(s) that ``aligned``
-        accepts, or seq_bucket(s) when none is (still correct, it just
-        pays the pads or copies)."""
-        base = self.seq_bucket(s)
-        for sp in self.seq_buckets():
-            if sp >= base and aligned(sp):
-                return sp
-        return base
-
     def prefill_seq_bucket(self, s: int) -> int:
         """The sequence bucket the whole-program prefill serves ``s`` at:
-        the first engine bucket where its attention pads nothing.  The
-        attention lattice's kv block is at least the lane width (128), so
-        prompts shorter than that run every GEMM at the 128 bucket."""
-        return self._first_aligned(s, self._attn_aligned)
+        the first engine bucket >= seq_bucket(s) where its attention pads
+        nothing, or seq_bucket(s) when none is (still correct, it just
+        pays the pads).  The attention lattice's kv block is at least the
+        lane width (128), so prompts shorter than that run every GEMM at
+        the 128 bucket."""
+        base = self.seq_bucket(s)
+        for sp in self.seq_buckets():
+            if sp >= base and self._attn_aligned(sp):
+                return sp
+        return base
 
     def prefill_seq_buckets(self, m_max: int | None = None) -> list[int]:
         """Every bucket the whole-program prefill serves prompts up to
@@ -715,141 +642,6 @@ class VortexServer:
         return sorted({
             self.prefill_seq_bucket(s) for s in self.seq_buckets(m_max)
         })
-
-    def chain_seq_bucket(self, s: int, bp: int = 1) -> int:
-        """The sequence bucket a chained prefill serves ``s`` at: the first
-        engine bucket where the whole chain is aligned (``_chain_aligned``)
-        — a misaligned chain stays correct, it just pays counted boundary
-        copies."""
-        return self._first_aligned(
-            s, functools.partial(self._chain_aligned, bp)
-        )
-
-    def _chain_layers(self) -> list:
-        """(spec, params) per layer in scan execution order (group-major),
-        unstacked once from the pos-stacked parameter tree."""
-        if self._chain_layer_cache is None:
-            cfg = self.cfg
-            n_pos = len(cfg.pattern)
-            layers = []
-            for g in range(cfg.n_groups):
-                for i in range(n_pos):
-                    p = jax.tree_util.tree_map(
-                        lambda t: t[g], self.params[f"pos{i}"]
-                    )
-                    layers.append((cfg.pattern[i], p))
-            self._chain_layer_cache = layers
-        return self._chain_layer_cache
-
-    def _head(self) -> jax.Array:
-        if self._head_cache is None:
-            self._head_cache = (
-                self.params["embed"].T if self.cfg.tie_embeddings
-                else self.params["lm_head"]
-            )
-        return self._head_cache
-
-    @staticmethod
-    def _chain_cache_leaf(t, kvb: int):
-        """One kv-cache leaf from a chain k/v projection: a fully-valid
-        handle's bucket buffer is consumed DIRECTLY when it already has the
-        cache length (zero copy); otherwise one dynamic_update_slice into
-        zeros — bitwise what the AOT prefill's jnp.pad emits."""
-        from repro.core.engine import LazyBucket
-
-        if isinstance(t, LazyBucket):
-            t = t.realize()  # identity for the chain's fully-valid handles
-        if t.shape[2] == kvb:
-            return t
-        buf = jnp.zeros(t.shape[:2] + (kvb,) + t.shape[3:], t.dtype)
-        return jax.lax.dynamic_update_slice(buf, t, (0,) * t.ndim)
-
-    def prefill_chained(self, bp: int, sp: int, batch, *, eager: bool = False):
-        """Whole-model prefill as a lazy handle chain: embed (plain ops) →
-        per-layer ``block_forward_lazy`` → final norm / head / softcap /
-        vocab mask via ``lazy_map`` — every engine boundary passes a
-        LazyBucket, so at a chain-aligned ``sp`` nothing unstages between
-        dispatches.  Returns ``(last_logits, cache)`` exactly like the AOT
-        prefill step: last_logits at the last real prompt position
-        ``batch["last"]`` (the chain's handles are fully valid to the bucket
-        width, so that row is read straight from the buffer), cache leaves
-        kv-bucket shaped.
-
-        ``eager=True`` runs the IDENTICAL dispatch sequence on plain arrays
-        (per-op stage/unstage) — the bit-identity reference the tests and
-        the bench gate compare against."""
-        from repro.core.engine import LazyBucket, lazy_map
-        from repro.models.layers import (
-            block_forward_lazy,
-            lazy_matmul,
-            norm,
-        )
-
-        cfg = self.cfg
-        eng = self.engine
-        lazy = not eager
-
-        # Pre-block embedding pipeline, bitwise the model's forward().
-        x = jnp.take(self.params["embed"], batch["tokens"], axis=0)
-        if cfg.embed_scale:
-            x = (
-                x.astype(jnp.float32) * math.sqrt(cfg.d_model)
-            ).astype(x.dtype)
-        if not cfg.use_rope:
-            p_idx = jnp.arange(sp).astype(jnp.float32)
-            half = cfg.d_model // 2
-            freq = 10000.0 ** (-jnp.arange(half, dtype=jnp.float32) / half)
-            ang = p_idx[:, None] * freq
-            pe = jnp.concatenate([jnp.sin(ang), jnp.cos(ang)], axis=-1)
-            x = x + pe[None].astype(x.dtype)
-        positions = jnp.arange(sp)
-
-        if lazy:
-            x = LazyBucket(x, sp, 1)
-        kvs = []
-        for spec, p in self._chain_layers():
-            x, kv = block_forward_lazy(
-                eng, p, x, cfg, spec, positions=positions, lazy=lazy,
-            )
-            kvs.append(kv)
-
-        x = lazy_map(lambda t: norm(t, self.params["final_norm"], cfg), x)
-        logits = lazy_matmul(eng, x, self._head(), lazy=lazy)
-        if cfg.logit_softcap is not None:
-            c = cfg.logit_softcap
-            logits = lazy_map(
-                lambda t: (
-                    jnp.tanh(t.astype(jnp.float32) / c) * c
-                ).astype(t.dtype),
-                logits,
-            )
-        if cfg.vocab_padded != cfg.vocab:
-            logits = lazy_map(
-                lambda t: jnp.where(
-                    jax.lax.broadcasted_iota(
-                        jnp.int32, t.shape, t.ndim - 1
-                    ) < cfg.vocab,
-                    t, -1e30,
-                ),
-                logits,
-            )
-        # The chain's handle is fully valid to the bucket width, so the
-        # last real prompt row is read without forcing a slice.
-        if isinstance(logits, LazyBucket):
-            logits = logits.buffer
-        last = logits[:, batch["last"]]
-
-        kvb = self.kv_bucket(sp)
-        n_pos = len(cfg.pattern)
-        cache: dict[str, Any] = {}
-        for i in range(n_pos):
-            ks, vs = [], []
-            for g in range(cfg.n_groups):
-                kv = kvs[g * n_pos + i]
-                ks.append(self._chain_cache_leaf(kv["k"], kvb))
-                vs.append(self._chain_cache_leaf(kv["v"], kvb))
-            cache[f"pos{i}"] = {"k": jnp.stack(ks), "v": jnp.stack(vs)}
-        return last, cache
 
     def warmup(
         self, *, max_batch: int = 1, m_max: int | None = None,
@@ -897,8 +689,7 @@ class VortexServer:
         keep = (
             "calls", "launches", "aligned_calls", "unaligned_calls",
             "stage_copies", "unstage_copies", "padded_calls",
-            "traced_calls", "forwarded", "realize_slices",
-            "fallbacks", "quarantined",
+            "traced_calls", "fallbacks", "quarantined",
         )
         estats = self.engine.stats()
         out = {
@@ -920,22 +711,16 @@ class VortexServer:
     # -- serving ------------------------------------------------------------
 
     def _prefill(self, tokens: np.ndarray):
-        """Prefill a (b, s) prompt through the configured path: the batch
+        """Prefill a (b, s) prompt through its AOT program: the batch
         bucket, the sequence bucket, the logits predicting the first new
         token, and the kv-bucket-shaped cache."""
         b, s = tokens.shape
         bp = self.batch_bucket(b)
-        if self.prefill == "chained" and self._prefill_chained_supported():
-            sp = self.chain_seq_bucket(s, bp)
-            batch = self._make_batch(bp, sp, tokens)
-            logits, cache = self.prefill_chained(bp, sp, batch)
-            self.stats["chained_prefills"] += 1
-        else:
-            sp = self.prefill_seq_bucket(s)
-            batch = self._make_batch(bp, sp, tokens)
-            logits, cache = self._prefill_exec_for(bp, sp, batch)(
-                self.params, batch
-            )
+        sp = self.prefill_seq_bucket(s)
+        batch = self._make_batch(bp, sp, tokens)
+        logits, cache = self._prefill_exec_for(bp, sp, batch)(
+            self.params, batch
+        )
         return bp, sp, logits, cache
 
     def _serve(self, tokens: np.ndarray, steps: int, forced=None):

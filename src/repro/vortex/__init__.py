@@ -58,7 +58,6 @@ __all__ = [
     "CompiledOp",
     "Engine",
     "EngineConfig",
-    "LazyBucket",
     "VortexDeprecationWarning",
     "WORKLOADS",
     "Workload",
@@ -66,7 +65,6 @@ __all__ = [
     "current_engine",
     "default_engine",
     "installed_engine",
-    "lazy_map",
     "make_workload",
     "ops",
     "pow2_bucket",
@@ -79,8 +77,6 @@ _LAZY: dict[str, tuple[str, str | None]] = {
     "CompiledOp": ("repro.vortex.handle", "CompiledOp"),
     "Engine": ("repro.vortex.engine", "Engine"),
     "EngineConfig": ("repro.vortex.config", "EngineConfig"),
-    "LazyBucket": ("repro.core.engine", "LazyBucket"),
-    "lazy_map": ("repro.core.engine", "lazy_map"),
     "pow2_bucket": ("repro.vortex.engine", "pow2_bucket"),
     "ops": ("repro.vortex.ops", None),
     "WORKLOADS": ("repro.core.workloads", "WORKLOADS"),

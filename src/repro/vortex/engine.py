@@ -269,15 +269,11 @@ class Engine:
             self._dispatch[key] = kern
         return kern
 
-    def dispatch(self, kind: str, *args: Any, lazy: bool = False,
-                 **kwargs: Any):
+    def dispatch(self, kind: str, *args: Any, **kwargs: Any):
         """Serve one call of a registered workload kind: ``args`` are the
-        runtime arrays (or engine :class:`~repro.core.engine.LazyBucket`
-        handles), ``kwargs`` the workload parameters (flags/strides).
-        ``lazy=True`` asks for the output as a LazyBucket handle —
-        best-effort, see ``VortexKernel.__call__``.  This is what
-        ``vortex.ops.<kind>(...)`` invokes."""
-        return self.op_kernel(kind, args, kwargs)(*args, lazy=lazy)
+        runtime arrays, ``kwargs`` the workload parameters (flags/strides).
+        This is what ``vortex.ops.<kind>(...)`` invokes."""
+        return self.op_kernel(kind, args, kwargs)(*args)
 
     # -- introspection ------------------------------------------------------
 
@@ -323,7 +319,6 @@ class Engine:
                     "aligned_calls": 0, "unaligned_calls": 0,
                     "stage_copies": 0, "unstage_copies": 0,
                     "padded_calls": 0, "traced_calls": 0,
-                    "forwarded": 0, "realize_slices": 0,
                     "fallbacks": 0, "quarantined": 0,
                 },
             )

@@ -356,42 +356,6 @@ def test_server_decode_greedy_tokens_stable_across_growth(mesh):
     np.testing.assert_array_equal(out_grow, out_again)
 
 
-@pytest.mark.parametrize("prefill", ["aot", "chained"])
-def test_server_first_token_comes_from_the_last_prompt_position(
-    mesh, prefill
-):
-    """An unaligned prompt pads to its sequence bucket; the logits that
-    predict the first new token are the last REAL prompt position's, not
-    the bucket's last (pad) row.  Served logits (prefill, then
-    teacher-forced decode) match the sessionless forward position by
-    position, and generate()'s first token is their argmax."""
-    from repro.launch.serve import Request, VortexServer
-    from repro.models.model import forward
-    from repro.models.registry import get_smoke_config
-
-    cfg = get_smoke_config("paper-gpt2-124m")
-    server = VortexServer(cfg, mesh, max_cache=128, prefill=prefill)
-    s, n_dec = 17, 3
-    sp = (server.chain_seq_bucket(s) if prefill == "chained"
-          else server.prefill_seq_bucket(s))
-    assert sp > s  # the prompt really is padded
-    toks = np.random.default_rng(3).integers(
-        0, cfg.vocab, (2, s + n_dec)
-    ).astype(np.int32)
-    ref = forward(
-        cfg, server.rules, server.params, jnp.asarray(toks),
-        mode="prefill", cache_len=s + n_dec,
-    )[0]
-    ref = np.asarray(ref[:, s - 1:, :cfg.vocab], np.float32)
-    got = server.score(toks, s)[..., :cfg.vocab]
-    assert got.shape == ref.shape == (2, n_dec + 1, cfg.vocab)
-    np.testing.assert_allclose(got, ref, atol=_tol(jnp.bfloat16), rtol=0)
-    first = server.generate(Request(tokens=toks[:, :s], max_new=1))
-    np.testing.assert_array_equal(first[:, 0], got[:, 0].argmax(-1))
-    if prefill == "chained":
-        assert server.stats["chained_prefills"] == 2
-
-
 @pytest.mark.parametrize("arch", ["paper-gpt2-124m", "phi4-mini-3.8b"])
 def test_server_counts_decode_attention_grid_steps(mesh, arch):
     """Each decode launch adds its program's decode-attention grid steps:

@@ -4,9 +4,10 @@ At factor f the first ``int(head_dim * f)`` dims of each head rotate by the
 split-half convention at frequencies ``theta^(-2i/rot)``, and the rest pass
 through unchanged.  Every path that applies RoPE is held to a plain float32
 split rotation, at 0.75 (phi4-mini) and at 1.0 (every other config): AOT
-prefill, the in-place decode (stacked cache, scalar and per-row positions),
-the restack decode, and the chained prefill.  At 1.0 the tables and the
-rotation are the ones the program had before the factor existed.
+prefill with and without an engine installed, the in-place decode (stacked
+cache, scalar and per-row positions) and the restack decode.  At 1.0 the
+tables and the rotation are the ones the program had before the factor
+existed.
 """
 import dataclasses
 
@@ -20,12 +21,11 @@ from repro.models.layers import (
     _split_heads,
     apply_rope,
     attn_forward,
-    attn_forward_lazy,
     rope_tables,
 )
 from repro.models.partitioning import make_rules
 from repro.models.registry import get_smoke_config
-from repro.vortex import Engine
+from repro.vortex import Engine, use
 
 FACTORS = [0.75, 1.0]
 THETA = 10000.0
@@ -173,16 +173,23 @@ def test_decode_key_rotated_at_its_position(factor, in_place, per_row):
 
 
 @pytest.mark.parametrize("factor", FACTORS)
-def test_chained_prefill_keys_rotated(factor):
+def test_engine_served_prefill_keys_rotated(factor):
+    """``test_prefill_keys_rotated`` with an engine installed while the
+    prefill is traced: attention is the traced engine dispatch that the
+    served AOT prefill program runs, and the cache keys are still the
+    rotated projections."""
     cfg = _cfg(factor)
     p = _weights(cfg, 5)
     x = jnp.asarray(np.random.default_rng(6).standard_normal((1, 24, 48)),
                     jnp.float32)
     eng = Engine("host_cpu", empirical_levels=())
-    _, kv = attn_forward_lazy(eng, p, x, cfg, cfg.pattern[0],
-                              positions=jnp.arange(24), lazy=False)
+    with use(eng):
+        _, cache = jax.jit(lambda p, x: attn_forward(
+            p, x, cfg, cfg.pattern[0], _rules(cfg), mode="prefill",
+            positions=jnp.arange(24), cache_len=32,
+        ))(p, x)
+    assert eng.stats()["attention"]["traced_calls"] == 1
     raw = np.asarray(_split_heads(x @ p["wk"], cfg.n_kv_heads))
-    # The engine's GEMM sums in its own order: float32 rounding of d=48.
-    np.testing.assert_allclose(np.asarray(kv["k"]),
-                               _plain(raw, np.arange(24), cfg.rotary_dim),
-                               rtol=1e-5, atol=1e-5)
+    got = np.asarray(cache["k"])[:, :, :24]
+    np.testing.assert_allclose(got, _plain(raw, np.arange(24), cfg.rotary_dim),
+                               **TOL)

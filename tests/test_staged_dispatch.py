@@ -11,9 +11,13 @@ Acceptance surface of the staging contract (DESIGN.md §4):
   * the copy/launch counters: an unaligned call is exactly ONE fused
     program launch plus its boundary copies, an aligned call is one launch
     with zero copies, and ``jnp.pad`` (the padded fallback) never fires;
-  * a Selection that cannot be honored raises instead of being clamped.
+  * a Selection that cannot be honored raises instead of being clamped;
+  * the staging pool retains at most ``staging_pool_cap`` idle buffer sets
+    (LRU eviction, MRU reuse) and eviction can never race an in-flight
+    dispatch (checked-out sets are not in the free list).
 """
 import dataclasses
+import threading
 
 import numpy as np
 import pytest
@@ -21,13 +25,14 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from repro.core.engine import _StagingPool
 from repro.core.workloads import (
     AttentionWorkload,
     Conv2dWorkload,
     GemmWorkload,
     SelectionDeviationError,
 )
-from repro.vortex import Engine
+from repro.vortex import Engine, EngineConfig
 
 RNG = np.random.default_rng(11)
 
@@ -133,42 +138,35 @@ def test_poisoned_staging_buffers_do_not_leak(engine, kind, params, make):
     )
 
 
-def test_unaligned_dispatch_is_one_launch_plus_boundary_copies():
+# One unaligned call per kind: (extent, stage copies, unstage copies).
+UNALIGNED_COPIES = {
+    "gemm": (61, 1, 1),  # only A is dynamic; B passes through
+    "grouped_gemm": (61, 1, 1),  # only x stages; w and counts pass through
+    "attention": (37, 3, 1),  # q, k and v all stage
+    # Only the k/v cache buffers stage; out is (b, h, 1, d): nothing to slice.
+    "decode_attention": (37, 2, 0),
+    "conv2d": (61, 1, 1),  # the im2col columns stage; the weights pass through
+}
+
+
+@pytest.mark.parametrize("kind,params,make", KIND_CASES,
+                         ids=[c[0] for c in KIND_CASES])
+def test_unaligned_dispatch_is_one_launch_plus_boundary_copies(
+    kind, params, make
+):
     """The acceptance counter: an unaligned extent issues exactly one
-    compiled-program launch, one staging copy per dynamic operand, one
-    output slice — and never a jnp.pad fallback."""
+    compiled-program launch (for grouped GEMM, one for all its ragged
+    groups), one staging copy per dynamic operand, one output slice where
+    the output is bucket-shaped — and never a jnp.pad fallback."""
+    m, stage, unstage = UNALIGNED_COPIES[kind]
     eng = Engine("host_cpu", empirical_levels=())
-    a, b = _gemm_args(61)
-    eng.dispatch("gemm", a, b)
-    d = eng.stats()["gemm"]
-    assert d["launches"] == 1
+    eng.dispatch(kind, *make(m), **params)
+    d = eng.stats()[kind]
+    assert d["calls"] == 1 and d["launches"] == 1
     assert d["unaligned_calls"] == 1 and d["aligned_calls"] == 0
-    assert d["stage_copies"] == 1  # only A is dynamic; B passes through
-    assert d["unstage_copies"] == 1
+    assert d["stage_copies"] == stage
+    assert d["unstage_copies"] == unstage
     assert d["padded_calls"] == 0 and d["traced_calls"] == 0
-
-    q, k, v = _attn_args(37)
-    eng.dispatch("attention", q, k, v)
-    d = eng.stats()["attention"]
-    assert d["launches"] == 1
-    assert d["stage_copies"] == 3  # q, k and v all stage
-    assert d["padded_calls"] == 0
-
-    qd, kd, vd, kv_len = _decode_args(37)
-    eng.dispatch("decode_attention", qd, kd, vd, kv_len)
-    d = eng.stats()["decode_attention"]
-    assert d["launches"] == 1
-    assert d["stage_copies"] == 2  # only the k/v cache buffers stage
-    assert d["unstage_copies"] == 0  # out is (b, h, 1, d): nothing to slice
-    assert d["padded_calls"] == 0
-
-    xg, wg, cg = _grouped_args(61)
-    eng.dispatch("grouped_gemm", xg, wg, cg)
-    d = eng.stats()["grouped_gemm"]
-    assert d["launches"] == 1  # ONE launch for all 6 ragged groups
-    assert d["stage_copies"] == 1  # only x stages; w and counts pass through
-    assert d["unstage_copies"] == 1
-    assert d["padded_calls"] == 0
 
 
 def test_aligned_dispatch_is_one_launch_zero_copies():
@@ -207,8 +205,6 @@ def test_concurrent_same_bucket_dispatch_no_cross_talk():
     bit-identical to its own sequential reference — a shared/serialized
     staging buffer would interleave tenants' rows — and the pool retains
     at most its cap of buffer sets afterwards."""
-    import threading
-
     eng = Engine("host_cpu", empirical_levels=())
     kern = eng.op_kernel("gemm", _gemm_args(8), {})
     bucket = kern.select(257).padded_m
@@ -325,3 +321,78 @@ def test_gemm_workload_staged_shapes_contract():
     shapes = wl.staged_shapes(sel, a, b)
     assert shapes == ((sel.padded_m, 96), None)
     assert wl.runtime_scalars(sel, a, b) == (61,)
+
+
+# ---------------------------------------------------------------------------
+# Staging pool: LRU retention bound
+# ---------------------------------------------------------------------------
+
+
+def test_staging_pool_lru_cap_and_mru_reuse():
+    pool = _StagingPool(cap=2)
+    need = {0: ((4, 4), jnp.float32)}
+    sets = [pool.acquire(need) for _ in range(3)]  # all checked out
+    assert len({id(s) for s in sets}) == 3
+    assert pool.retained == []  # in-flight sets are NOT in the free list
+    for s in sets:
+        pool.release(s)
+    assert len(pool.retained) == 2  # LRU (first released) evicted
+    assert pool.retained[-1] is sets[-1]
+    assert all(s is not sets[0] for s in pool.retained)
+    assert pool.acquire(need) is sets[-1]  # MRU-first reuse
+
+
+def test_staging_pool_cap_zero_retains_nothing():
+    pool = _StagingPool(cap=0)
+    need = {0: ((4, 4), jnp.float32)}
+    pool.release(pool.acquire(need))
+    assert pool.retained == []
+
+
+def test_engine_config_threads_pool_cap():
+    e = Engine(EngineConfig(
+        hardware="host_cpu", empirical_levels=(), staging_pool_cap=0,
+    ))
+    kern = e.op_kernel("gemm", (_arr((5, 16)), _arr((16, 8))), {})
+    m = next(m for m in range(3, 257) if kern.select(m).padded_m > m)
+    out = kern(_arr((m, 16)), _arr((16, 8)))
+    assert out.shape == (m, 8)
+    assert kern.dispatch_stats.stage_copies >= 1
+    pools = [entry.pool for entry in kern._exec_cache.values()]
+    assert pools and all(p.cap == 0 and p.retained == [] for p in pools)
+
+
+def test_pool_eviction_never_races_in_flight():
+    """cap=1 under concurrent unaligned dispatch: every result stays
+    bit-identical to its serial reference (a set in use is checked out, so
+    eviction can only ever drop idle sets) and at most one set is retained
+    after the burst."""
+    e = Engine(EngineConfig(
+        hardware="host_cpu", empirical_levels=(), staging_pool_cap=1,
+    ))
+    kern = e.op_kernel("gemm", (_arr((5, 16)), _arr((16, 8))), {})
+    m = next(m for m in range(3, 257) if kern.select(m).padded_m > m)
+    w = _arr((16, 8))
+    xs = [_arr((m, 16)) for _ in range(8)]
+    refs = [np.asarray(kern(x, w)) for x in xs]
+
+    errors: list = []
+
+    def worker(i):
+        try:
+            for _ in range(4):
+                got = np.asarray(kern(xs[i], w))
+                np.testing.assert_array_equal(got, refs[i])
+        except Exception as exc:  # pragma: no cover - failure path
+            errors.append((i, exc))
+
+    threads = [
+        threading.Thread(target=worker, args=(i,)) for i in range(len(xs))
+    ]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert not errors, errors[:2]
+    for entry in kern._exec_cache.values():
+        assert len(entry.pool.retained) <= 1

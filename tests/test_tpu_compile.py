@@ -37,7 +37,6 @@ from repro.kernels.attention import (
 )
 from repro.kernels.gemm import vortex_gemm
 from repro.kernels.grouped_gemm import vortex_grouped_gemm
-from repro.launch.serve import chain_gemm_sigs
 from repro.models.config import SHAPES
 from repro.models.registry import ARCH_IDS, cell_supported, get_config
 from repro.vortex import Engine, EngineConfig
@@ -49,6 +48,22 @@ LM_HEAD = (D, GPT2.vocab_padded)
 EXPERT_UP = (GRANITE.d_model, GRANITE.moe.d_ff_expert)
 EXPERT_DOWN = (GRANITE.moe.d_ff_expert, GRANITE.d_model)
 E = GRANITE.moe.num_experts
+
+
+def gemm_sigs(cfg) -> list[tuple[int, int]]:
+    """Every (K, N) GEMM signature of ``cfg``'s attention-plus-dense
+    block and its LM head: q/k/v/o projections, the MLP pair, the head."""
+    d, hd = cfg.d_model, cfg.resolved_head_dim
+    sigs = {
+        (d, cfg.n_heads * hd),        # wq
+        (d, cfg.n_kv_heads * hd),     # wk / wv
+        (cfg.n_heads * hd, d),        # wo
+        (d, cfg.vocab_padded),        # lm head
+    }
+    if any(spec.mlp == "dense" for spec in cfg.pattern):
+        sigs.add((d, cfg.d_ff))       # w_in / w_gate
+        sigs.add((cfg.d_ff, d))       # w_out
+    return sorted(sigs)
 
 
 @pytest.fixture(scope="module")
@@ -402,7 +417,7 @@ def test_selectable_tiles_fit_the_vmem_limit_passed_to_the_compiler(engine):
     counts no more than the VMEM limit; the count covers what the kernel's
     specs allocate for the tiles the selector picks; and the built
     executable hands that limit to the compiler."""
-    gemms = [GemmWorkload(M=None, N=n, K=k) for k, n in chain_gemm_sigs(GPT2)]
+    gemms = [GemmWorkload(M=None, N=n, K=k) for k, n in gemm_sigs(GPT2)]
     grouped = [
         GroupedGemmWorkload(C=None, G=E, E=E, N=n, K=k)
         for k, n in (EXPERT_UP, EXPERT_DOWN)
